@@ -861,11 +861,12 @@ def main():
             ap.error(f"unknown wire codecs {unknown}; "
                      f"known: {list(WIRE_CODECS)} or 'all'")
 
-    if args.sharded and jax.device_count() < SHARDED_DEVICES:
+    # decided from the environment, before anything touches a backend: a
+    # parent holding a device would keep it from the re-executed child
+    force = f"--xla_force_host_platform_device_count={SHARDED_DEVICES}"
+    if args.sharded and force not in os.environ.get("XLA_FLAGS", ""):
         env = dict(os.environ)
-        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                            " --xla_force_host_platform_device_count="
-                            f"{SHARDED_DEVICES}").strip()
+        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + force).strip()
         env.setdefault("JAX_PLATFORMS", "cpu")
         argv = [sys.executable, "-m", "benchmarks.panel_bench", "--sharded"]
         if args.wire:  # keep a combined --sharded --wire request intact

@@ -23,6 +23,28 @@ def get_config(arch: str) -> ModelConfig:
     return _REGISTRY[key]()
 
 
+PRESETS = ("cpu", "chip", "pod")
+# decoder layers of the 'chip' preset: the depth at which m=4 agents'
+# training state (params, grads, moments and the round's temporaries)
+# fits one 16 GB v5e chip at published widths
+CHIP_LAYERS = 1
+
+
+def preset_config(cfg: ModelConfig, preset: str) -> ModelConfig:
+    """The config the launchers run under ``--preset``: 'pod' is the
+    published config; 'chip' keeps every published width and cuts depth
+    to CHIP_LAYERS (training and serving agree on it, so a merged model
+    saved by one loads in the other); 'cpu' also cuts widths, for tests
+    on the CPU."""
+    if preset == "cpu":
+        return cfg.reduced(d_model=128, layers=2, vocab=256)
+    if preset == "chip":
+        return cfg.depth_cut(CHIP_LAYERS)
+    if preset != "pod":
+        raise ValueError(f"unknown preset {preset!r}; known: {PRESETS}")
+    return cfg
+
+
 def list_archs():
     _load_all()
     return sorted(_REGISTRY)
@@ -35,6 +57,7 @@ def _load_all():
                                xlstm_1_3b, yi_34b)
 
 
-__all__ = ["get_config", "list_archs", "register", "ModelConfig", "ShapeConfig",
+__all__ = ["get_config", "list_archs", "preset_config", "PRESETS",
+           "CHIP_LAYERS", "register", "ModelConfig", "ShapeConfig",
            "INPUT_SHAPES", "AttentionConfig", "MoEConfig", "RecurrentConfig",
            "LayerSpec", "DistConfig"]

@@ -143,6 +143,16 @@ class ModelConfig:
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
 
+    def depth_cut(self, layers: int) -> "ModelConfig":
+        """Every published width, ``layers`` decoder layers (encoder and
+        dense front layers cut alongside): the only cut a config may take
+        to fit a chip and still stand for the published model."""
+        return self.replace(
+            num_layers=layers,
+            encoder_layers=min(self.encoder_layers, layers),
+            dense_ff_first_k=min(self.dense_ff_first_k, layers),
+            mtp_depth=min(self.mtp_depth, 1))
+
     def reduced(self, d_model: int = 256, layers: Optional[int] = None,
                 vocab: int = 512, experts: int = 4) -> "ModelConfig":
         """A tiny same-family variant for CPU smoke tests (<=2 layers, d<=512)."""

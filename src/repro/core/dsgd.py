@@ -19,7 +19,7 @@ Two state layouts:
   This is the hot path used by launch/train.py and benchmarked in
   benchmarks/panel_bench.py.
 
-One round = per-agent local step(s) (vmapped grad + optimizer; zero
+One round = per-agent local step(s) (per-agent grad + optimizer; zero
 cross-agent traffic) followed by gossip mixing with the scheduler's W^(t).
 
 ``loss_fn(params, batch, rng) -> (loss, aux)`` is any per-agent objective
@@ -53,7 +53,10 @@ def _init_agent_params(init_params: Callable, m: int, rng,
         p = init_params(rng)
         return jax.tree.map(
             lambda x: jnp.broadcast_to(x[None], (m,) + x.shape), p)
-    return jax.vmap(init_params)(jax.random.split(rng, m))
+    # agent by agent: the same bits as a vmap, and the TPU compiler takes
+    # one agent's shapes (~1 s) instead of agent-stacked ones (~11 s per
+    # olmo-1b FFN matrix)
+    return jax.lax.map(init_params, jax.random.split(rng, m))
 
 
 def _place(tree, shardings):
@@ -277,17 +280,15 @@ def _res_constrain(v, spec, k: str):
     return panel_mod._constrain_group(v, spec, k)
 
 
-def _res_read(stored, sts, *, use_pallas: bool = False,
-              interpret: bool = True):
+def _res_read(stored, sts, *, use_pallas: bool = False):
     """Decode a stored state-panel group dict to its f32 compute view
     (groups without a storage entry pass through)."""
-    return {k: (sts[k].read(v, use_pallas=use_pallas, interpret=interpret)
+    return {k: (sts[k].read(v, use_pallas=use_pallas)
                 if k in sts else v)
             for k, v in stored.items()}
 
 
-def _res_write(panel, sts, key, spec=None, *, use_pallas: bool = False,
-               interpret: bool = True):
+def _res_write(panel, sts, key, spec=None, *, use_pallas: bool = False):
     """Encode an f32 state-panel group dict into storage (per-group SR
     keys via residency.storage_keys — sorted-group fold order, the
     _wire_keys discipline); ``spec`` adds the sharding constraints."""
@@ -295,8 +296,7 @@ def _res_write(panel, sts, key, spec=None, *, use_pallas: bool = False,
     out = {}
     for k, v in panel.items():
         if k in sts:
-            v = sts[k].write(v, key=keys[k], use_pallas=use_pallas,
-                             interpret=interpret)
+            v = sts[k].write(v, key=keys[k], use_pallas=use_pallas)
         out[k] = _res_constrain(v, spec, k) if spec is not None else v
     return out
 
@@ -308,31 +308,27 @@ def _res_init(panel, sts):
             for k, v in panel.items()}
 
 
-def _opt_read(opt, sts, mom_keys, *, use_pallas: bool = False,
-              interpret: bool = True):
+def _opt_read(opt, sts, mom_keys, *, use_pallas: bool = False):
     """Optimizer state -> its f32 compute view: moment entries decode
     through the storage, everything else (step_count) passes through."""
-    return {k: (_res_read(v, sts, use_pallas=use_pallas,
-                          interpret=interpret)
+    return {k: (_res_read(v, sts, use_pallas=use_pallas)
                 if k in mom_keys else v)
             for k, v in opt.items()}
 
 
-def _opt_write(opt, sts, mom_keys, key, spec, *, use_pallas: bool = False,
-               interpret: bool = True):
+def _opt_write(opt, sts, mom_keys, key, spec, *, use_pallas: bool = False):
     """Encode the updated f32 moments back into storage, one folded key
     per moment entry (sorted order) so m/v draw independent SR bits."""
     present = sorted(k for k in opt if k in mom_keys)
     out = dict(opt)
     for i, k in enumerate(present):
         mk = None if key is None else jax.random.fold_in(key, i)
-        out[k] = _res_write(opt[k], sts, mk, spec, use_pallas=use_pallas,
-                            interpret=interpret)
+        out[k] = _res_write(opt[k], sts, mk, spec, use_pallas=use_pallas)
     return out
 
 
 def _fused_opt_update(gpan, opt, pan, optimizer, sts, spec, key, *,
-                      use_pallas: bool = False, interpret: bool = True):
+                      use_pallas: bool = False):
     """Fused moment update: the stored int8 groups run the single-sweep
     Pallas kernel (kernels/opt_fused.py) — decode, the optimizer's
     shared elementwise core, and the SR re-encode all in VMEM, HBM
@@ -382,7 +378,7 @@ def _fused_opt_update(gpan, opt, pan, optimizer, sts, spec, key, *,
             opt["v"][k]["q"], opt["v"][k]["scale"],
             um, uv, lr, bc1, bc2, group=st.group, core=optimizer.core,
             transform_fwd=st.transform_fwd, transform_inv=st.transform_inv,
-            use_pallas=use_pallas, interpret=interpret)
+            use_pallas=use_pallas)
         new_pan[k] = p2
         new_m[k] = _res_constrain({"q": qm2, "scale": sm2}, spec, k)
         new_v[k] = _res_constrain({"q": qv2, "scale": sv2}, spec, k)
@@ -586,8 +582,7 @@ def unpanelize_state(state, spec):
 def make_panel_segment(loss_fn: Callable, optimizer: Optimizer,
                        local_steps: int, spec, *, wire_dtype=None,
                        monitor: bool = True, telemetry: bool = False,
-                       use_pallas: bool = False,
-                       interpret: bool = True, donate: bool = True,
+                       use_pallas: bool = False, donate: bool = True,
                        fused=None,
                        param_shardings=None, in_shardings=None):
     """Donated, scanned panel driver: one dispatch per SCHEDULE SEGMENT.
@@ -667,7 +662,7 @@ def make_panel_segment(loss_fn: Callable, optimizer: Optimizer,
     ``Schedule.last_live``). LIVE agents run the round normally. A DEAD
     agent's parameter, moment, EF-residual and merge-statistics rows
     pass through the round bit-exactly: it takes no local steps (its
-    rows of the vmapped grad/optimizer update are discarded — the rng
+    rows of the per-agent grad/optimizer update are discarded — the rng
     stream is consumed identically, so survivors' draws match the
     fault-free run), and the caller must hand in the matching DEGRADED W
     (Schedule does: topology.degrade_to_live / fully_connected_live), so
@@ -780,6 +775,28 @@ def make_panel_segment(loss_fn: Callable, optimizer: Optimizer,
         (l, aux), g = jax.value_and_grad(loss_fn, has_aux=True)(p, b, r)
         return g, l
 
+    def local_grads(pan, batch, rngs):
+        """(grad panel, (m,) losses) of every agent's local step."""
+        if spec.sharded:
+            # agent rows live on different devices: one vmapped program
+            params = panel_mod.from_panel(pan, spec,
+                                          leaf_shardings=param_shardings)
+            grads, losses = jax.vmap(one)(params, batch, rngs)
+            return panel_mod.to_panel(grads, spec), losses
+
+        # one device holds every row: agents run one after another. A
+        # vmap over the (m, D) panel fuses the panel<->leaf relayouts into
+        # the forward/backward, and the TPU compiler's code then grows
+        # with D: olmo-1b at 1 layer, m=4 compiled to 735 MB of code in
+        # 776 s with 30 GB of host memory, against 30 MB in 16 s this way
+        def agent(xs):
+            row, b, r = xs
+            g, l = one(panel_mod.from_panel(row, spec), b, r)
+            gp = panel_mod.to_panel(jax.tree.map(lambda x: x[None], g), spec)
+            return {k: v[0] for k, v in gp.items()}, l
+
+        return jax.lax.map(agent, (pan, batch, rngs))
+
     def segment(state, batches, Ws, rng, active=None, global_rounds=None,
                 live=None):
         m = next(iter(state["panel"].values())).shape[0]
@@ -804,8 +821,7 @@ def make_panel_segment(loss_fn: Callable, optimizer: Optimizer,
             # branches — idle rounds never touch the stored bits
             if not res_err or e is None:
                 return e
-            return _res_read(e, res_err, use_pallas=res_pallas,
-                             interpret=interpret)
+            return _res_read(e, res_err, use_pallas=res_pallas)
 
         def err_enc(ne, ekey, eold, W):
             # re-encode the post-mix residual; idle ROWS of W (unmatched
@@ -816,7 +832,7 @@ def make_panel_segment(loss_fn: Callable, optimizer: Optimizer,
             if not res_err or ne is None:
                 return ne
             enc = _res_write(ne, res_err, ekey, spec,
-                             use_pallas=res_pallas, interpret=interpret)
+                             use_pallas=res_pallas)
             if eold is not None:
                 ir = jnp.all(W == jnp.eye(m, dtype=W.dtype), axis=1)
                 enc = {k: (jax.tree.map(
@@ -858,11 +874,8 @@ def make_panel_segment(loss_fn: Callable, optimizer: Optimizer,
                 pan, opt, mstat = carry
                 batch, r = xs
                 rngs = jax.random.split(r, m)
-                params = panel_mod.from_panel(
-                    pan, spec, leaf_shardings=param_shardings)
                 with scope("dsgd.local_grad"):
-                    grads, losses = jax.vmap(one)(params, batch, rngs)
-                gpan = panel_mod.to_panel(grads, spec)
+                    gpan, losses = local_grads(pan, batch, rngs)
                 if not plain_merge and merger.local_stat:
                     upd = merger.update_local(mstat, gpan)
                     mstat = upd if alive is None else freeze(upd, mstat)
@@ -877,7 +890,7 @@ def make_panel_segment(loss_fn: Callable, optimizer: Optimizer,
                         new_pan, new_opt = _fused_opt_update(
                             gpan, opt, pan, optimizer, res_mom, spec,
                             _res_key(r, "moments", res_mom_key),
-                            use_pallas=res_pallas, interpret=interpret)
+                            use_pallas=res_pallas)
                     else:
                         # moment storage fusion: decode -> update ->
                         # re-encode inside the SAME donated step (the f32
@@ -885,14 +898,13 @@ def make_panel_segment(loss_fn: Callable, optimizer: Optimizer,
                         # carried buffer); the SR key folds off the
                         # LOCAL-STEP rng so every step draws fresh bits
                         opt_f = _opt_read(opt, res_mom, mom_keys,
-                                          use_pallas=res_pallas,
-                                          interpret=interpret)
+                                          use_pallas=res_pallas)
                         new_pan, new_opt = jax.vmap(optimizer.update)(
                             gpan, opt_f, pan)
                         new_opt = _opt_write(
                             new_opt, res_mom, mom_keys,
                             _res_key(r, "moments", res_mom_key), spec,
-                            use_pallas=res_pallas, interpret=interpret)
+                            use_pallas=res_pallas)
                 if alive is None:
                     loss = jnp.mean(losses)
                     gn = panel_mod.panel_norm(gpan, axis_mean=True)
@@ -919,7 +931,7 @@ def make_panel_segment(loss_fn: Callable, optimizer: Optimizer,
             sync = lv == 2
             not_live = ~alive
             kw = dict(wire_dtype=wire_dtype, use_pallas=use_pallas,
-                      interpret=interpret, spec=spec, key=wkey)
+                      spec=spec, key=wkey)
             idle = jnp.all(W == jnp.eye(m, dtype=W.dtype))
             is_full = (None if plain_merge else
                        (glob if glob is not None else
@@ -949,7 +961,7 @@ def make_panel_segment(loss_fn: Callable, optimizer: Optimizer,
                 mixed, _, ne = merging_mod.merge_panel(
                     p, merger, stats=mstat, spec=spec,
                     wire_dtype=wire_dtype, key=wkey, err=err_dec(e),
-                    use_pallas=use_pallas, interpret=interpret,
+                    use_pallas=use_pallas,
                     live=alive)
                 return mixed, err_enc(ne, ekey, None, None)
 
@@ -970,7 +982,8 @@ def make_panel_segment(loss_fn: Callable, optimizer: Optimizer,
                 # (defense in depth — the per-row idle rule already
                 # restores them under a lossy codec)
                 y = jnp.where(row_mask(not_live, x), pan[k], x)
-                mu = jnp.tensordot(lw, y.astype(jnp.float32), axes=1)
+                mu = jnp.tensordot(lw, y.astype(jnp.float32), axes=1,
+                                   precision="highest")
                 y = jnp.where(row_mask(sync, y), mu[None].astype(y.dtype),
                               y)
                 out_pan[k] = panel_mod._constrain_group(y, spec, k)
@@ -1038,7 +1051,7 @@ def make_panel_segment(loss_fn: Callable, optimizer: Optimizer,
                     "grad_norm_max": jnp.max(gns)}
             if monitor:
                 mets["consensus"] = panel_mod.consensus_distance(
-                    out_pan, use_pallas=use_pallas, interpret=interpret,
+                    out_pan, use_pallas=use_pallas,
                     spec=spec, live=alive)
             if telemetry:
                 mets.update(agent_mets(
@@ -1081,7 +1094,7 @@ def make_panel_segment(loss_fn: Callable, optimizer: Optimizer,
                        (glob if glob is not None else
                         jnp.all(W == jnp.full((m, m), 1.0 / m, W.dtype))))
             kw = dict(wire_dtype=wire_dtype, use_pallas=use_pallas,
-                      interpret=interpret, spec=spec, key=wkey)
+                      spec=spec, key=wkey)
 
             if monitor:
                 def comm(args):
@@ -1094,7 +1107,7 @@ def make_panel_segment(loss_fn: Callable, optimizer: Optimizer,
                 def idle_fn(args):
                     p, e = args
                     return p, e, panel_mod.consensus_distance(
-                        p, use_pallas=use_pallas, interpret=interpret,
+                        p, use_pallas=use_pallas,
                         spec=spec)
 
                 def gossip_fn(args):
@@ -1105,7 +1118,7 @@ def make_panel_segment(loss_fn: Callable, optimizer: Optimizer,
                     mixed, _, ne = merging_mod.merge_panel(
                         p, merger, stats=mstat, spec=spec,
                         wire_dtype=wire_dtype, key=wkey, err=err_dec(e),
-                        use_pallas=use_pallas, interpret=interpret)
+                        use_pallas=use_pallas)
                     return (mixed, err_enc(ne, ekey, None, None),
                             jnp.zeros((), jnp.float32))
 
@@ -1135,7 +1148,7 @@ def make_panel_segment(loss_fn: Callable, optimizer: Optimizer,
                     mixed, _, ne = merging_mod.merge_panel(
                         p, merger, stats=mstat, spec=spec,
                         wire_dtype=wire_dtype, key=wkey, err=err_dec(e),
-                        use_pallas=use_pallas, interpret=interpret)
+                        use_pallas=use_pallas)
                     return mixed, err_enc(ne, ekey, None, None)
 
                 if plain_merge:
@@ -1164,8 +1177,7 @@ def make_panel_segment(loss_fn: Callable, optimizer: Optimizer,
             # bit-matches a fresh init of the synced params.
             pan, opt, werr, mstat = carry
             mstat_f = {name: _res_read(grp, res_stat,
-                                       use_pallas=res_pallas,
-                                       interpret=interpret)
+                                       use_pallas=res_pallas)
                        for name, grp in mstat.items()}
             (pan, opt, werr, mstat_f), mets = round_core(
                 (pan, opt, werr, mstat_f), W, batch_r, r, glob, lv)
@@ -1176,8 +1188,7 @@ def make_panel_segment(loss_fn: Callable, optimizer: Optimizer,
             for i, name in enumerate(sorted(mstat_f)):
                 ki = None if skey is None else jax.random.fold_in(skey, i)
                 enc = _res_write(mstat_f[name], res_stat, ki, None,
-                                 use_pallas=res_pallas,
-                                 interpret=interpret)
+                                 use_pallas=res_pallas)
                 if lv is not None:
                     det = _res_init(mstat_f[name], res_stat)
                     old = mstat[name]

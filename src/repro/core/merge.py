@@ -28,7 +28,8 @@ def weighted_merge(params_stacked, weights):
     w = jnp.asarray(weights, jnp.float32)
     w = w / jnp.sum(w)
     return jax.tree.map(
-        lambda x: jnp.tensordot(w, x.astype(jnp.float32), axes=1), params_stacked)
+        lambda x: jnp.tensordot(w, x.astype(jnp.float32), axes=1,
+                                precision="highest"), params_stacked)
 
 
 def uniform_merge(params_stacked):

@@ -441,7 +441,7 @@ def _pallas_ok(use_pallas: bool, spec: Optional[PanelSpec]) -> bool:
 
 
 def _mix_dense_groups(panel, W, *, wire_dtype, use_pallas, block_d,
-                      interpret, spec, key, err, with_mean):
+                      spec, key, err, with_mean):
     """Shared body of mix_dense / mix_dense_mean. Returns (mixed, means,
     new_err); means/new_err are None unless requested.
 
@@ -480,8 +480,7 @@ def _mix_dense_groups(panel, W, *, wire_dtype, use_pallas, block_d,
         e = err[k] if err is not None else None
         with scope(f"wire.encode.{k}"):
             xw, back, ne = codecs[k].encode(x, key=keys[k], err=e,
-                                            use_pallas=pallas,
-                                            interpret=interpret)
+                                            use_pallas=pallas)
         if getattr(codecs[k], "delta_mix", False):
             # sparse-innovation codecs (topk): xw is the updated MIRROR
             # panel and the mix runs in CHOCO's damped delta form
@@ -499,10 +498,10 @@ def _mix_dense_groups(panel, W, *, wire_dtype, use_pallas, block_d,
             x32 = x.astype(jnp.float32)
             Wd = W32 - jnp.eye(m, dtype=jnp.float32)
             if pallas:
-                d32 = gossip_mix_panel(Wd, xw, block_d=block_d,
-                                       interpret=interpret)
+                d32 = gossip_mix_panel(Wd, xw, block_d=block_d)
             else:
-                d32 = Wd @ xw.astype(jnp.float32)
+                d32 = jnp.matmul(Wd, xw.astype(jnp.float32),
+                                 precision="highest")
             gamma = getattr(codecs[k], "gamma", 1.0)
             y32 = x32 + gamma * d32.astype(jnp.float32)
             with scope(f"wire.decode.{k}"):
@@ -529,12 +528,14 @@ def _mix_dense_groups(panel, W, *, wire_dtype, use_pallas, block_d,
         fold_k = fold and not (pallas and xw.dtype != jnp.float32)
         Wk = Wop if fold_k else W32
         if pallas:
-            y = gossip_mix_panel(Wk, xw, block_d=block_d,
-                                 interpret=interpret)
+            y = gossip_mix_panel(Wk, xw, block_d=block_d)
             if fold_k:
                 y, mu = y[:m], y[m].astype(jnp.float32)
         else:
-            y32 = Wk @ xw.astype(jnp.float32)
+            # f32-exact: at the TPU's default precision the MXU would round
+            # the parameters to bf16 (the merged model then differs by
+            # ~3e-3 relative between layouts)
+            y32 = jnp.matmul(Wk, xw.astype(jnp.float32), precision="highest")
             if fold_k:
                 y32, mu = y32[:m], y32[m]
             y = y32.astype(xw.dtype)
@@ -560,7 +561,7 @@ def _mix_dense_groups(panel, W, *, wire_dtype, use_pallas, block_d,
 
 @scope("panel.mix")
 def mix_dense(panel, W, *, wire_dtype=None, use_pallas: bool = False,
-              block_d: int = 512, interpret: bool = True,
+              block_d: int = 512,
               spec: Optional[PanelSpec] = None, key=None, err=None):
     """Theta <- W Theta: one f32-accumulating matmul per dtype group.
 
@@ -573,14 +574,14 @@ def mix_dense(panel, W, *, wire_dtype=None, use_pallas: bool = False,
     return to ``(mixed, new_err)``."""
     mixed, _, new_err = _mix_dense_groups(
         panel, W, wire_dtype=wire_dtype, use_pallas=use_pallas,
-        block_d=block_d, interpret=interpret, spec=spec, key=key, err=err,
+        block_d=block_d, spec=spec, key=key, err=err,
         with_mean=False)
     return mixed if err is None else (mixed, new_err)
 
 
 @scope("panel.mix_mean")
 def mix_dense_mean(panel, W, *, wire_dtype=None, use_pallas: bool = False,
-                   block_d: int = 512, interpret: bool = True,
+                   block_d: int = 512,
                    spec: Optional[PanelSpec] = None, key=None, err=None):
     """mix_dense with the consensus mean folded into the mixing matmul.
 
@@ -589,7 +590,7 @@ def mix_dense_mean(panel, W, *, wire_dtype=None, use_pallas: bool = False,
     for :func:`consensus_from_mean`; new_err is None when ``err`` is."""
     return _mix_dense_groups(
         panel, W, wire_dtype=wire_dtype, use_pallas=use_pallas,
-        block_d=block_d, interpret=interpret, spec=spec, key=key, err=err,
+        block_d=block_d, spec=spec, key=key, err=err,
         with_mean=True)
 
 
@@ -679,7 +680,7 @@ def _live_weights(live, m):
 
 @scope("panel.merged")
 def merged(panel, *, use_pallas: bool = False, block_d: int = 512,
-           interpret: bool = True, spec: Optional[PanelSpec] = None,
+           spec: Optional[PanelSpec] = None,
            live=None):
     """The (counterfactual) averaged model as {dtype: (D_dtype,)} f32.
 
@@ -690,11 +691,11 @@ def merged(panel, *, use_pallas: bool = False, block_d: int = 512,
     if live is not None:
         w = _live_weights(live, next(iter(panel.values())).shape[0])
         return {k: _constrain_group(
-            jnp.tensordot(w, x.astype(jnp.float32), axes=1), spec, k,
+            jnp.tensordot(w, x.astype(jnp.float32), axes=1,
+                          precision="highest"), spec, k,
             merged_panel=True) for k, x in panel.items()}
     if _pallas_ok(use_pallas, spec):
-        return {k: panel_mean_consensus(x, block_d=block_d,
-                                        interpret=interpret)[0]
+        return {k: panel_mean_consensus(x, block_d=block_d)[0]
                 for k, x in panel.items()}
     return {k: _constrain_group(jnp.mean(x.astype(jnp.float32), axis=0),
                                 spec, k, merged_panel=True)
@@ -709,7 +710,7 @@ def merged_tree(panel, spec: PanelSpec):
 
 @scope("panel.consensus")
 def consensus_distance(panel, *, use_pallas: bool = False,
-                       block_d: int = 512, interpret: bool = True,
+                       block_d: int = 512,
                        spec: Optional[PanelSpec] = None, live=None):
     """Xi_t = sqrt((1/m) sum_k ||theta_k - bar||^2) in one fused pass.
     Sharded: per-shard partial sums of squares + ONE scalar reduce.
@@ -724,15 +725,14 @@ def consensus_distance(panel, *, use_pallas: bool = False,
         n = jnp.maximum(jnp.sum(lf), 1.0)
         for x in panel.values():
             x32 = x.astype(jnp.float32)
-            mean = jnp.tensordot(lf / n, x32, axes=1)
+            mean = jnp.tensordot(lf / n, x32, axes=1, precision="highest")
             total = total + jnp.sum(
                 lf[:, None] * jnp.square(x32 - mean[None]))
         return jnp.sqrt(total / n)
     pallas = _pallas_ok(use_pallas, spec)
     for x in panel.values():
         if pallas:
-            _, sq = panel_mean_consensus(x, block_d=block_d,
-                                         interpret=interpret)
+            _, sq = panel_mean_consensus(x, block_d=block_d)
         else:
             x32 = x.astype(jnp.float32)
             mean = jnp.mean(x32, axis=0, keepdims=True)
@@ -767,7 +767,7 @@ def panel_norm(panel, axis_mean: bool = False, rows=None):
             if rows is None:
                 x32 = jnp.mean(x32, axis=0)
             else:
-                x32 = jnp.tensordot(rows, x32, axes=1)
+                x32 = jnp.tensordot(rows, x32, axes=1, precision="highest")
         total = total + jnp.sum(jnp.square(x32))
     return jnp.sqrt(total)
 

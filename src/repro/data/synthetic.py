@@ -49,8 +49,23 @@ class SyntheticClassification:
                                    min_per_agent=8)
 
 
+# the largest vocab that gets dense (domains, vocab, vocab) transition
+# tables: 32 MB at 8 domains. At olmo-1b's 50304 they would take 81 GB
+DENSE_MAX_VOCAB = 1024
+# structured chain: a next token inside the domain's subset lands at
+# (t * _STEP + jitter) mod subset size, jitter ~ 1/(k+1) over _JITTER
+_STEP = 7919
+_JITTER = 8
+
+
 @dataclass
 class SyntheticLM:
+    """Per-domain Markov token streams. Up to DENSE_MAX_VOCAB the chains
+    have dense Dirichlet transition tables; above it a structured chain
+    with the same domain skew: from token t the next token stays in the
+    domain's token subset with the probability mass the dense tables
+    expect there, at a t-dependent position, else it is uniform over the
+    vocab — O(batch) work per step and no table."""
     vocab: int = 256
     num_domains: int = 8
     order_skew: float = 4.0
@@ -59,6 +74,14 @@ class SyntheticLM:
 
     def __post_init__(self):
         rng = np.random.default_rng(self.seed)
+        if self.vocab > DENSE_MAX_VOCAB:
+            n = self.vocab / self.num_domains
+            self._stay = (self.order_skew * n
+                          / (self.order_skew * n
+                             + 0.05 * (self.vocab - n)))
+            w = 1.0 / np.arange(1, _JITTER + 1)
+            self._jitter_p = w / w.sum()
+            return
         # per-domain Markov transition matrices concentrated on a domain-
         # specific token subset => strongly domain-skewed statistics
         self._trans = np.empty((self.num_domains, self.vocab, self.vocab),
@@ -80,6 +103,17 @@ class SyntheticLM:
         doms = rng.choice(self.num_domains, size=batch, p=domain_probs)
         out = np.empty((batch, seq_len + 1), np.int32)
         out[:, 0] = rng.integers(0, self.vocab, size=batch)
+        if self._trans is None:
+            lo = (doms * self.vocab) // self.num_domains
+            size = ((doms + 1) * self.vocab) // self.num_domains - lo
+            for t in range(seq_len):
+                jit = rng.choice(_JITTER, size=batch, p=self._jitter_p)
+                inside = lo + (out[:, t].astype(np.int64) * _STEP
+                               + jit) % size
+                out[:, t + 1] = np.where(
+                    rng.random(batch) < self._stay, inside,
+                    rng.integers(0, self.vocab, size=batch))
+            return out
         for t in range(seq_len):
             probs = self._trans[doms, out[:, t]]
             cum = probs.cumsum(axis=1)

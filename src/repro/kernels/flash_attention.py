@@ -6,8 +6,8 @@ max / denominator / accumulator live in VMEM scratch across k steps.
 BlockSpecs tile Q/K/V into (block, head_dim) VMEM windows; MXU-aligned
 block sizes (multiples of 128) are chosen by the wrapper in ops.py.
 
-Validated in interpret mode against kernels/ref.py (CPU container); on a
-real TPU the same pallas_call lowers to Mosaic.
+Validated in interpret mode against kernels/ref.py on CPU; on a TPU the
+same pallas_call lowers to Mosaic.
 """
 from __future__ import annotations
 
@@ -18,6 +18,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 NEG_INF = -1e30
 
@@ -67,7 +69,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 
 def flash_attention_bh(q, k, v, *, causal=True, window=None, scale=None,
-                       block_q=128, block_k=128, interpret=True):
+                       block_q=128, block_k=128, interpret=None):
     """Single (batch*head)-merged call. q,k,v: (BH, S, hd)."""
     BH, S, hd = q.shape
     scale = scale if scale is not None else 1.0 / np.sqrt(hd)
@@ -96,7 +98,7 @@ def flash_attention_bh(q, k, v, *, causal=True, window=None, scale=None,
                 pltpu.VMEM((block_q, 1), jnp.float32),
                 pltpu.VMEM((block_q, hd), jnp.float32),
             ],
-            interpret=interpret,
+            interpret=interpret_mode(interpret),
         )(qi, ki_, vi)
 
     return jax.vmap(one)(q, k, v)

@@ -15,15 +15,19 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
+
 
 def _mix_kernel(w_ref, t_ref, o_ref):
     w = w_ref[...].astype(jnp.float32)  # (m, m)
     t = t_ref[...].astype(jnp.float32)  # (m, block_d)
-    o_ref[...] = jnp.dot(w, t, preferred_element_type=jnp.float32).astype(
+    o_ref[...] = jnp.dot(w, t, preferred_element_type=jnp.float32,
+                         precision=jax.lax.Precision.HIGHEST).astype(
         o_ref.dtype)
 
 
-def gossip_mix_panel(W, theta, *, block_d: int = 512, interpret: bool = True):
+def gossip_mix_panel(W, theta, *, block_d: int = 512,
+                     interpret: bool | None = None):
     """W: (n, m); theta: (m, D) -> W @ theta, D tiled into VMEM blocks.
 
     n == m for a plain mixing matrix; the consensus-folded path passes
@@ -46,6 +50,6 @@ def gossip_mix_panel(W, theta, *, block_d: int = 512, interpret: bool = True):
         ],
         out_specs=pl.BlockSpec((n, block_d), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((n, Dp), theta.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(W, theta)
     return out[:, :D]

@@ -32,6 +32,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
+
 
 def _weighted_kernel(x_ref, w_ref, o_ref):
     x = x_ref[...].astype(jnp.float32)            # (m, block_d)
@@ -59,7 +61,8 @@ def _pad_cols(x, block_d):
     return (jnp.pad(x, ((0, 0), (0, pad))) if pad else x), D + pad
 
 
-def weighted_colmerge(x, w, *, block_d: int = 512, interpret: bool = True):
+def weighted_colmerge(x, w, *, block_d: int = 512,
+                      interpret: bool | None = None):
     """x: (m, D) panel; w: (m, D) per-coordinate weights -> (D,) f32
     weighted column merge sum_k w_kj x_kj / sum_k w_kj.
 
@@ -77,13 +80,13 @@ def weighted_colmerge(x, w, *, block_d: int = 512, interpret: bool = True):
         in_specs=[data_spec, data_spec],
         out_specs=pl.BlockSpec((1, block_d), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, Dp), jnp.float32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(xp, wp)
     return out[0, :D]
 
 
 def ties_colmerge(tau, thresh, *, block_d: int = 512,
-                  interpret: bool = True):
+                  interpret: bool | None = None):
     """tau: (m, D) deviation panel; thresh: (m, 1) f32 per-row trim
     thresholds (kernels/ref.py: ties_thresh_ref) -> (D,) f32 sign-elected
     agreeing mean of the trimmed deviations (0 where nothing survives)."""
@@ -100,6 +103,6 @@ def ties_colmerge(tau, thresh, *, block_d: int = 512,
         ],
         out_specs=pl.BlockSpec((1, block_d), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, Dp), jnp.float32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(tp, thresh)
     return out[0, :D]

@@ -1,9 +1,9 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-``interpret=True`` (default here) runs the kernel bodies in Python on CPU —
-the validation mode for this container; pass ``interpret=False`` on real
-TPU hardware. Model code keeps ``use_pallas=False`` by default so the same
-graph lowers for the CPU dry-run client (see DESIGN.md §8).
+Interpret mode is decided by the platform (``repro.kernels.interpret_mode``):
+the kernels compile to Mosaic on a TPU and run in the Pallas interpreter
+elsewhere. Model code keeps ``use_pallas=False`` by default so the same
+graph lowers for the CPU dry-run client.
 """
 from __future__ import annotations
 
@@ -16,10 +16,9 @@ import numpy as np
 from repro.kernels.flash_attention import flash_attention_bh
 
 
-@partial(jax.jit, static_argnames=("causal", "window", "block_q", "block_k",
-                                   "interpret"))
+@partial(jax.jit, static_argnames=("causal", "window", "block_q", "block_k"))
 def flash_attention(q, k, v, *, causal=True, window=None, block_q=128,
-                    block_k=128, interpret=True):
+                    block_k=128):
     """q: (B,S,H,hd); k,v: (B,S,Kv,hd) with H % Kv == 0 (GQA expanded here).
 
     Returns (B,S,H,hd)."""
@@ -33,13 +32,12 @@ def flash_attention(q, k, v, *, causal=True, window=None, block_q=128,
     kb = k.transpose(0, 2, 1, 3).reshape(B * H, S, hd)
     vb = v.transpose(0, 2, 1, 3).reshape(B * H, S, hd)
     out = flash_attention_bh(qb, kb, vb, causal=causal, window=window,
-                             block_q=block_q, block_k=block_k,
-                             interpret=interpret)
+                             block_q=block_q, block_k=block_k)
     return out.reshape(B, H, S, hd).transpose(0, 2, 1, 3)
 
 
-@partial(jax.jit, static_argnames=("block_d", "interpret"))
-def gossip_mix(W, params_stacked, *, block_d=512, interpret=True):
+@partial(jax.jit, static_argnames=("block_d",))
+def gossip_mix(W, params_stacked, *, block_d=512):
     """Kernel-backed Theta <- W Theta over an agent-stacked pytree.
 
     Flattening goes through the PanelSpec engine (core/panel.py): leaves are
@@ -50,13 +48,12 @@ def gossip_mix(W, params_stacked, *, block_d=512, interpret=True):
     from repro.core import panel as panel_mod
     spec = panel_mod.make_spec(params_stacked)
     panel = panel_mod.to_panel(params_stacked, spec)
-    mixed = panel_mod.mix_dense(panel, W, use_pallas=True, block_d=block_d,
-                                interpret=interpret)
+    mixed = panel_mod.mix_dense(panel, W, use_pallas=True, block_d=block_d)
     return panel_mod.from_panel(mixed, spec)
 
 
-@partial(jax.jit, static_argnames=("block_d", "interpret"))
-def panel_stats(params_stacked, *, block_d=512, interpret=True):
+@partial(jax.jit, static_argnames=("block_d",))
+def panel_stats(params_stacked, *, block_d=512):
     """Kernel-backed fused panel statistics over an agent-stacked pytree:
     (merged f32 pytree, consensus distance Xi). One panel_reduce kernel
     call per dtype group — single pass over the parameters."""
@@ -68,8 +65,7 @@ def panel_stats(params_stacked, *, block_d=512, interpret=True):
     means = {}
     total = jnp.zeros((), jnp.float32)
     for k, x in panel.items():
-        mean, sq = panel_mean_consensus(x, block_d=block_d,
-                                        interpret=interpret)
+        mean, sq = panel_mean_consensus(x, block_d=block_d)
         means[k] = mean
         total = total + sq
     merged = panel_mod.from_panel(means, spec, cast=False)

@@ -12,18 +12,19 @@ sweep: HBM sees only the stored int8 q + grouped scales (plus the grad
 and param panels the update must touch anyway) — no f32 moment panel is
 ever materialized.
 
-Why the re-encode can fuse at all: ``_int4_blocking`` snaps ``block_d``
-to a whole number of scale groups, so every scale group lies entirely
-inside one grid block and the fresh per-group amax/127 scales of the
-UPDATED moments are computable block-locally — no second sweep, unlike
-the per-row (group=None) layout, whose row amax needs all of D. Hence
+Why the re-encode can fuse at all: the kernel runs on the grouped-scale
+view (wire_quant.GroupView: one scale group per view row), so every
+scale group lies entirely inside one grid block and the fresh per-group
+amax/127 scales of the UPDATED moments are one lane reduce per view row
+— no second sweep, unlike the per-row (group=None) layout, whose row
+amax needs all of D. Hence
 the fused path exists only for GROUPED int8 storages ('int8'/'int8g');
 per-row 'int8r' and f32/bf16 keep the unfused decode->update->encode.
 
 Hyperparameters lr/bc1/bc2 arrive as (m, 1) per-agent columns, not
 scalars: step_count diverges across agent rows after a RESYNC re-init,
-so the bias corrections do too. They ride the same resident (m, 1)
-BlockSpec as the wire kernels' row scales.
+so the bias corrections do too. The grid's first axis is the agent, so
+each step reads its agent's (1, 1) entry.
 
 Randomness follows wire_quant's portable contract: the uniforms are
 INPUT panels threaded from the jax PRNG key schedule (bit-identical to
@@ -37,45 +38,39 @@ from __future__ import annotations
 
 import functools
 
-import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
 from repro.kernels.ref import adamw_fused_int8_ref
-from repro.kernels.wire_quant import (_int4_blocking, _pad_cols,
-                                      _pad_group_scale)
+from repro.kernels.wire_quant import GroupView
 
 
 def _identity(x):
     return x
 
 
-def _adamw_fused_kernel(group, core, fwd, inv,
+def _adamw_fused_kernel(core, fwd, inv,
                         g_ref, p_ref, qm_ref, sm_ref, qv_ref, sv_ref,
                         um_ref, uv_ref, lr_ref, bc1_ref, bc2_ref,
                         po_ref, qmo_ref, smo_ref, qvo_ref, svo_ref):
-    # decode: grouped dequant (scale expand is a VMEM repeat) + inverse
-    # companding — bitwise the ref dequantize_int8_grouped_ref
-    sm = jnp.repeat(sm_ref[...], group, axis=1)
-    sv = jnp.repeat(sv_ref[...], group, axis=1)
-    m = inv(qm_ref[...].astype(jnp.float32) * sm)
-    v = inv(qv_ref[...].astype(jnp.float32) * sv)
-    # the shared optimizer core; lr/bc1/bc2 are resident (m, 1) columns
+    # decode: grouped dequant (one scale per view row, broadcast along
+    # lanes) + inverse companding — bitwise dequantize_int8_grouped_ref
+    m = inv(qm_ref[...].astype(jnp.float32) * sm_ref[...])
+    v = inv(qv_ref[...].astype(jnp.float32) * sv_ref[...])
+    # the shared optimizer core; lr/bc1/bc2 are this agent's (1, 1) entry
     p, m, v = core(g_ref[...], m, v, p_ref[...],
                    lr=lr_ref[...], bc1=bc1_ref[...], bc2=bc2_ref[...])
     po_ref[...] = p
-    mloc, bd = p.shape
-    sg = bd // group
 
     def encode(z, u, s_out, q_out):
-        # fresh block-local grouped scales of the UPDATED moment — the
-        # block holds whole groups, so this matches the ref's global
+        # fresh grouped scales of the UPDATED moment: a view row is one
+        # whole group, so this matches the ref's global
         # int8_group_scale_ref exactly (max is order-independent)
-        amax = jnp.max(jnp.abs(z).reshape(mloc, sg, group), axis=2)
+        amax = jnp.max(jnp.abs(z), axis=1, keepdims=True)
         s = jnp.where(amax > 0, amax, 1.0) / 127.0
         s_out[...] = s
-        se = jnp.repeat(s, group, axis=1)
-        q_out[...] = jnp.clip(jnp.floor(z / se + u),
+        q_out[...] = jnp.clip(jnp.floor(z / s + u),
                               -127.0, 127.0).astype(jnp.int8)
 
     encode(fwd(m), um_ref[...], smo_ref, qmo_ref)
@@ -94,7 +89,7 @@ def _col(a, m):
 def adamw_fused_int8_panel(g, p, qm, sm, qv, sv, um, uv, lr, bc1, bc2, *,
                            group: int = 128, core, transform_fwd=None,
                            transform_inv=None, block_d: int = 512,
-                           interpret: bool = True):
+                           interpret: bool | None = None):
     """Fused AdamW step on companded grouped-int8 moments.
 
     g, p: (m, D) f32; qm/qv: (m, D) int8; sm/sv: (m, ceil(D/group)) f32
@@ -105,43 +100,30 @@ def adamw_fused_int8_panel(g, p, qm, sm, qv, sv, um, uv, lr, bc1, bc2, *,
     m, D = g.shape
     fwd = transform_fwd if transform_fwd is not None else _identity
     inv = transform_inv if transform_inv is not None else _identity
-    bd = _int4_blocking(D, group, block_d)
-    gp, Dp = _pad_cols(g.astype(jnp.float32), bd)
-    pp, _ = _pad_cols(p, bd)
-    qmp, _ = _pad_cols(qm, bd)
-    qvp, _ = _pad_cols(qv, bd)
-    ump, _ = _pad_cols(um, bd)
-    uvp, _ = _pad_cols(uv, bd)
-    smp = _pad_group_scale(sm, Dp, group)
-    svp = _pad_group_scale(sv, Dp, group)
-    nd = Dp // bd
-    sg = bd // group
-    G = -(-D // group)
-    data = pl.BlockSpec((m, bd), lambda i: (0, i))
-    scale = pl.BlockSpec((m, sg), lambda i: (0, i))
-    col = pl.BlockSpec((m, 1), lambda i: (0, 0))
+    view = GroupView(m, D, group, block_d)
+    data, scale = view.data_spec(), view.scale_spec()
+    col = pl.BlockSpec((None, 1, 1), lambda a, i: (a, 0, 0))
     p_new, qm_new, sm_new, qv_new, sv_new = pl.pallas_call(
-        functools.partial(_adamw_fused_kernel, group, core, fwd, inv),
-        grid=(nd,),
+        functools.partial(_adamw_fused_kernel, core, fwd, inv),
+        grid=view.grid,
         in_specs=[data, data, data, scale, data, scale,
                   data, data, col, col, col],
         out_specs=[data, data, scale, data, scale],
-        out_shape=(jax.ShapeDtypeStruct((m, Dp), jnp.float32),
-                   jax.ShapeDtypeStruct((m, Dp), jnp.int8),
-                   jax.ShapeDtypeStruct((m, Dp // group), jnp.float32),
-                   jax.ShapeDtypeStruct((m, Dp), jnp.int8),
-                   jax.ShapeDtypeStruct((m, Dp // group), jnp.float32)),
-        interpret=interpret,
-    )(gp, pp, qmp, smp, qvp, svp, ump, uvp,
-      _col(lr, m), _col(bc1, m), _col(bc2, m))
-    return (p_new[:, :D], qm_new[:, :D], sm_new[:, :G],
-            qv_new[:, :D], sv_new[:, :G])
+        out_shape=(view.data_shape(jnp.float32),
+                   view.data_shape(jnp.int8), view.scale_shape(),
+                   view.data_shape(jnp.int8), view.scale_shape()),
+        interpret=interpret_mode(interpret),
+    )(view.data(g.astype(jnp.float32)), view.data(p), view.data(qm),
+      view.scale(sm), view.data(qv), view.scale(sv), view.data(um),
+      view.data(uv), *(_col(h, m)[:, :, None] for h in (lr, bc1, bc2)))
+    return (view.undata(p_new), view.undata(qm_new), view.unscale(sm_new),
+            view.undata(qv_new), view.unscale(sv_new))
 
 
 def adamw_fused_int8(g, p, qm, sm, qv, sv, um, uv, lr, bc1, bc2, *,
                      group: int = 128, core, transform_fwd=None,
                      transform_inv=None, use_pallas: bool = True,
-                     interpret: bool = True, block_d: int = 512):
+                     interpret: bool | None = None, block_d: int = 512):
     """Dispatch wrapper: the Pallas kernel when ``use_pallas`` (the
     replicated/interpret path), else the shardable XLA ref composition —
     SPMD specs fall back here exactly as the storage codecs do via

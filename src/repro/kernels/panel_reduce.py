@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
+
 
 def _reduce_kernel(t_ref, mean_ref, acc_ref):
     i = pl.program_id(0)
@@ -36,7 +38,7 @@ def _reduce_kernel(t_ref, mean_ref, acc_ref):
 
 
 def panel_mean_consensus(theta, *, block_d: int = 512,
-                         interpret: bool = True):
+                         interpret: bool | None = None):
     """theta: (m, D) -> (mean (D,) f32, sq scalar f32).
 
     ``sq`` is the total squared deviation sum_{k,j} (theta_kj - mean_j)^2;
@@ -61,6 +63,6 @@ def panel_mean_consensus(theta, *, block_d: int = 512,
             jax.ShapeDtypeStruct((1, Dp), jnp.float32),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(theta)
     return mean[0, :D], acc[0, 0]

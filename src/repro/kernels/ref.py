@@ -28,7 +28,8 @@ def attention_ref(q, k, v, *, causal: bool = True, window=None,
 
 def gossip_mix_ref(W, theta):
     """W: (m, m); theta: (m, D) -> W @ theta in f32 accumulation."""
-    return (W.astype(jnp.float32) @ theta.astype(jnp.float32)).astype(theta.dtype)
+    return jnp.matmul(W.astype(jnp.float32), theta.astype(jnp.float32),
+                      precision="highest").astype(theta.dtype)
 
 
 def panel_mean_consensus_ref(theta):
@@ -143,24 +144,24 @@ def dequantize_int8_grouped_ref(q, scale, group: int = 128):
 
 def pack_int4_ref(q):
     """(m, D) int4-valued int8 -> (m, ceil(D/2)) uint8 packed nibbles:
-    even column in the LOW nibble, odd column in the HIGH nibble (an odd
-    tail packs against a zero nibble). This IS the wire byte layout —
-    two quantized values per byte."""
+    byte j holds column j in its LOW nibble and column j + ceil(D/2) in
+    its HIGH nibble (an odd D packs the last byte against a zero nibble).
+    This IS the wire byte layout — two quantized values per byte, halves
+    that a TPU kernel reads as two lane-aligned blocks."""
     m, D = q.shape
-    if D % 2:
-        q = jnp.pad(q, ((0, 0), (0, 1)))
-    pair = q.reshape(m, -1, 2).astype(jnp.uint8) & 0xF
-    return (pair[:, :, 0] | (pair[:, :, 1] << 4)).astype(jnp.uint8)
+    P = (D + 1) // 2
+    lo = q[:, :P].astype(jnp.uint8) & 0xF
+    hi = jnp.pad(q[:, P:], ((0, 0), (0, 2 * P - D))).astype(jnp.uint8) & 0xF
+    return (lo | (hi << 4)).astype(jnp.uint8)
 
 
 def unpack_int4_ref(p, D: int):
     """(m, ceil(D/2)) uint8 packed nibbles -> (m, D) int8, sign-extended
     ((n ^ 8) - 8 maps the nibble back to [-8, 7]). Exact inverse of
     pack_int4_ref for values in [-8, 7]."""
-    m = p.shape[0]
     lo = (p & 0xF).astype(jnp.int8)
     hi = ((p >> 4) & 0xF).astype(jnp.int8)
-    q = jnp.stack([lo, hi], axis=2).reshape(m, -1)[:, :D]
+    q = jnp.concatenate([lo, hi], axis=1)[:, :D]
     return ((q ^ 8) - 8).astype(jnp.int8)
 
 

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run driver (deliverable e) + roofline source (g).
 
 For every (architecture x input-shape x mesh) this lowers + compiles the
@@ -35,30 +32,34 @@ Variants (the §Perf levers; "baseline" is the paper-faithful config):
                 int8 moment storage) — the record's memory_analysis and
                 ``resident_bytes_per_agent`` extra show the per-agent
                 HBM drop vs the plain panel variant
+
+Run as a script, it compiles against 512 forced host (CPU) devices; the
+flag is set only in ``__main__``, so importing this module leaves
+``XLA_FLAGS`` alone.
 """
+import argparse
+import dataclasses
+import json
+import os
+import time
+import traceback
 
-import argparse  # noqa: E402
-import dataclasses  # noqa: E402
-import json  # noqa: E402
-import time  # noqa: E402
-import traceback  # noqa: E402
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-import numpy as np  # noqa: E402
-from jax.sharding import NamedSharding  # noqa: E402
-from jax.sharding import PartitionSpec as P  # noqa: E402
-
-from repro.configs import INPUT_SHAPES, get_config, list_archs  # noqa: E402
-from repro.core import dsgd  # noqa: E402
-from repro.core import panel as panel_mod  # noqa: E402
-from repro.launch import mesh as mesh_mod  # noqa: E402
-from repro.models import build_model  # noqa: E402
-from repro.models.sharding import (TRAIN_RULES, activation_sharding,  # noqa: E402
+from repro.configs import INPUT_SHAPES, get_config, list_archs
+from repro.core import dsgd
+from repro.core import panel as panel_mod
+from repro.launch import mesh as mesh_mod
+from repro.models import build_model
+from repro.models.sharding import (TRAIN_RULES, activation_sharding,
                                    resolve, serve_rules)
-from repro.optim import make_optimizer  # noqa: E402
-from repro.utils import flops as flops_mod  # noqa: E402
-from repro.utils.hlo import collective_bytes  # noqa: E402
+from repro.optim import make_optimizer
+from repro.utils import flops as flops_mod
+from repro.utils.hlo import collective_bytes
 
 PEAK_FLOPS = 197e12  # bf16 / chip (v5e)
 HBM_BW = 819e9  # B/s / chip
@@ -305,7 +306,7 @@ def run_train_extrapolated(cfg, shape, multi_pod, variant, rec):
     rec["compile_s"] = round(time.time() - t0, 2)
 
     def costs(c):
-        ca = _cost_dict(c)
+        ca = c.cost_analysis() or {}
         _, coll, _ = collective_bytes(c.as_text())
         return (float(ca.get("flops", 0.0)),
                 float(ca.get("bytes accessed", 0.0)), float(coll))
@@ -338,14 +339,6 @@ def run_train_extrapolated(cfg, shape, multi_pod, variant, rec):
     rec["memory"]["per_device_total"] = int(per_dev_total)
     rec["memory"]["fits_16gb"] = bool(per_dev_total < 16e9)
     return rec, hlo_flops, hlo_bytes, coll_total, mesh.devices.size
-
-
-def _cost_dict(compiled):
-    """compiled.cost_analysis() across jaxlib versions: dict or [dict]."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, list):
-        ca = ca[0] if ca else {}
-    return ca
 
 
 def roofline_terms(hlo_flops, hlo_bytes, coll_bytes, chips):
@@ -418,7 +411,7 @@ def run_pair(arch, shape_name, multi_pod, variant="baseline", outdir=None):
             rec["memory"]["per_device_total"] = int(per_dev_total)
             rec["memory"]["fits_16gb"] = bool(per_dev_total < 16e9)
 
-            ca = _cost_dict(compiled)
+            ca = compiled.cost_analysis() or {}
             hlo_flops = float(ca.get("flops", 0.0))
             hlo_bytes = float(ca.get("bytes accessed", 0.0))
             rec["cost"] = {"flops_per_device": hlo_flops,
@@ -502,4 +495,5 @@ def main():
 
 
 if __name__ == "__main__":
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     main()

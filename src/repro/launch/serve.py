@@ -6,8 +6,10 @@ CPU demo — heterogeneous-length requests streaming through slotted decode:
         --concurrency 4 --requests 8 --max-new 16 [--stream]
 
 optionally restoring the artifact produced by ``launch.train
---save-merged`` via ``--restore``. ``--one-shot`` runs the plain static
-batched :func:`repro.serving.generate` path instead.
+--save-merged`` via ``--restore`` (same ``--preset`` as the training run:
+``chip`` keeps every published width and cuts depth, as in training).
+``--one-shot`` runs the plain static batched :func:`repro.serving.generate`
+path instead.
 """
 from __future__ import annotations
 
@@ -20,7 +22,8 @@ import numpy as np
 
 from repro import telemetry
 from repro.checkpoint import restore
-from repro.configs import get_config
+from repro.configs import PRESETS, get_config, preset_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import build_model
 from repro.serving import Request, ServingEngine, generate
 
@@ -40,10 +43,15 @@ def _request_inputs(cfg, i, S, k_prompt, k_mm, k_frames):
     return np.asarray(toks, np.int32), extras
 
 
-def main():
+def main(argv=None):
+    """Serve the requests ``argv`` (default: the command line) describes.
+    Returns {"outputs": {rid: tokens}, "requests", "model", "params",
+    "max_len"} for callers that drive the server in-process (None for
+    ``--one-shot``)."""
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="olmo-1b")
-    ap.add_argument("--preset", default="cpu", choices=["cpu", "pod"])
+    ap.add_argument("--preset", default="cpu", choices=PRESETS)
     ap.add_argument("--concurrency", type=int, default=4,
                     help="decode slots held live at once")
     ap.add_argument("--requests", type=int, default=8,
@@ -69,11 +77,9 @@ def main():
     ap.add_argument("--profile", default="",
                     help="capture a jax profiler trace of the serving "
                          "loop into this logdir")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
-    cfg = get_config(args.arch)
-    if args.preset == "cpu":
-        cfg = cfg.reduced(d_model=128, layers=2, vocab=256)
+    cfg = preset_config(get_config(args.arch), args.preset)
     model = build_model(cfg)
     # independent PRNG streams: params / prompts / patch embeds / frame
     # embeds / sampling (the seed path used to reuse ONE key for all five)
@@ -103,7 +109,7 @@ def main():
         print(f"generated {out.shape} in {dt:.2f}s "
               f"({B * args.max_new / dt:.1f} tok/s)")
         print(out[:2])
-        return
+        return None
 
     # two prompt-length buckets -> exactly two prefill compiles
     lengths = [args.prompt_len, max(1, args.prompt_len // 2)]
@@ -152,6 +158,8 @@ def main():
         print(f"req {rid}:", out[rid])
     if args.events:
         print(f"events: {args.events}")
+    return {"outputs": out, "requests": reqs, "model": model,
+            "params": params, "max_len": max_len}
 
 
 if __name__ == "__main__":

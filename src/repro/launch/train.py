@@ -9,8 +9,11 @@ rounds) with the segment's mixing matrices precomputed and stacked, H
 DISTINCT batches per round (Algorithm 1's local SGD), on-device metric
 accumulation, and a single device_get per segment.
 
-On this CPU container use ``--preset cpu`` (tiny model, 1-device mesh); on a
-pod the same script drives the production training mesh: ``--mesh train``
+``--preset cpu`` runs a tiny model on the CPU (tests); ``--preset chip``
+keeps every published width of the architecture and cuts depth only, so
+m agents fit one 16 GB v5e chip (``--mesh host`` lays the agent rows over
+all of a host's chips instead); on a pod the same script drives the
+production training mesh: ``--mesh train``
 builds mesh.make_training_mesh and shards the panel rows over
 ('pod','agent') and the flat D axis over 'fsdp' (core/panel.shard_spec), so
 the fused mix lowers to per-shard matmuls with fsdp-local collectives
@@ -43,7 +46,7 @@ from repro import merging as merging_mod
 from repro import telemetry
 from repro import wire as wire_mod
 from repro.checkpoint import Checkpointer, save
-from repro.configs import get_config
+from repro.configs import PRESETS, get_config, preset_config
 from repro.core import dsgd
 from repro.core import faults as faults_mod
 from repro.core import merge as merge_mod
@@ -51,6 +54,7 @@ from repro.core import panel as panel_mod
 from repro.core.schedule import make_schedule
 from repro.data.synthetic import SyntheticLM, make_agent_lm_batches
 from repro.launch import mesh as mesh_mod
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import build_model
 from repro.optim import make_optimizer
 from repro.residency import parse_policy
@@ -59,13 +63,16 @@ from repro.telemetry.metrics import fused_moments_auto, resident_bytes_model
 
 def build_mesh(kind: str, preset: str, cfg):
     """Resolve --mesh: None (single-device/replicated panels) or a
-    ('pod','agent','fsdp','model') training mesh the panel is sharded on."""
+    ('pod','agent','fsdp','model') training mesh the panel is sharded on
+    (``host``: the agent axis over every chip of this host)."""
     if kind == "auto":
         kind = "train" if preset == "pod" else "none"
     if kind == "none":
         return None
     if kind == "train":
         return mesh_mod.make_training_mesh(cfg.dist.agents_per_pod)
+    if kind == "host":
+        return mesh_mod.make_host_mesh()
     if kind == "debug":
         need = 8
         if jax.device_count() < need:
@@ -74,13 +81,6 @@ def build_mesh(kind: str, preset: str, cfg):
                 f"--xla_force_host_platform_device_count={need}")
         return mesh_mod.make_debug_mesh(agents=2, fsdp=2, model=2)
     raise ValueError(kind)
-
-
-def build_cpu_preset(cfg, agents):
-    cfg = cfg.reduced(d_model=128, layers=2, vocab=256)
-    cfg = cfg.replace(dist=dataclasses.replace(cfg.dist,
-                                               agents_per_pod=agents))
-    return cfg
 
 
 def sample_segment_batches(lm, mixtures, rounds, local_steps, batch, seq,
@@ -97,10 +97,17 @@ def sample_segment_batches(lm, mixtures, rounds, local_steps, batch, seq,
             for k in per_round[0]}
 
 
-def main():
+def main(argv=None):
+    """Run the training job ``argv`` (default: the command line) describes.
+    Returns {"history": per-round records, "merged": the merged model as an
+    f32 pytree} for callers that drive the job in-process."""
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="olmo-1b")
-    ap.add_argument("--preset", default="cpu", choices=["cpu", "pod"])
+    ap.add_argument("--preset", default="cpu", choices=PRESETS,
+                    help="cpu: tiny widths for tests; chip: published "
+                         "widths, depth cut to fit one v5e chip; pod: the "
+                         "published config")
     ap.add_argument("--agents", type=int, default=8)
     ap.add_argument("--rounds", type=int, default=30)
     ap.add_argument("--local-steps", type=int, default=4)
@@ -166,10 +173,11 @@ def main():
                          "the per-segment rng split means runs are only "
                          "trajectory-comparable at the SAME cadence")
     ap.add_argument("--mesh", default="auto",
-                    choices=["auto", "none", "train", "debug"],
+                    choices=["auto", "none", "train", "host", "debug"],
                     help="shard the (m, D) panel on a training mesh: rows "
                          "over ('pod','agent'), D over 'fsdp' (auto: train "
-                         "for --preset pod, none for cpu)")
+                         "for --preset pod, none otherwise; host: agent "
+                         "rows over this host's chips)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default="results/train")
     ap.add_argument("--save-merged", default="")
@@ -218,14 +226,14 @@ def main():
     ap.add_argument("--profile", default="",
                     help="capture a jax profiler trace of the training "
                          "loop into this logdir (view with tensorboard/"
-                         "xprof; degrades to a warning where the profiler "
-                         "backend is unavailable)")
-    args = ap.parse_args()
+                         "xprof); a profiler that cannot start is an error")
+    args = ap.parse_args(argv)
 
-    cfg = get_config(args.arch)
-    if args.preset == "cpu":
-        cfg = build_cpu_preset(cfg, args.agents)
+    cfg = preset_config(get_config(args.arch), args.preset)
     m = args.agents
+    if args.preset == "cpu":
+        cfg = cfg.replace(dist=dataclasses.replace(cfg.dist,
+                                                   agents_per_pod=m))
     model = build_model(cfg)
     opt = make_optimizer(args.optimizer, args.lr, weight_decay=5e-4,
                          total_steps=args.rounds * args.local_steps)
@@ -332,8 +340,13 @@ def main():
             lambda p: eval_loss(p, b), pan, spec, stats=mstat, live=lv))
 
     def _local_mean(pan, b, lv):
-        losses = jax.vmap(eval_loss, in_axes=(0, None))(
-            panel_mod.from_panel(pan, spec), b)
+        if spec.sharded:  # rows on different devices: one vmapped program
+            losses = jax.vmap(eval_loss, in_axes=(0, None))(
+                panel_mod.from_panel(pan, spec), b)
+        else:  # agent by agent, as dsgd's local step (compile cost)
+            losses = jax.lax.map(
+                lambda row: eval_loss(panel_mod.from_panel(row, spec), b),
+                pan)
         if lv is None:
             return jnp.mean(losses)
         lf = lv.astype(jnp.float32)
@@ -542,15 +555,16 @@ def main():
     os.makedirs(args.out, exist_ok=True)
     with open(os.path.join(args.out, tag + ".json"), "w") as f:
         json.dump({"args": vars(args), "history": history}, f, indent=1)
+    # merge with the RUN'S operator (+ its stats), not the uniform mean —
+    # the merged model must be the one whose merged_eval the history just
+    # reported; under a fault plan only agents alive at the end contribute
+    merged = merge_mod.merged_panel_tree(
+        state["panel"], spec, stats=state.get("merge_stat"),
+        live=alive_after(args.rounds - 1))
     if args.save_merged:
-        # merge with the RUN'S operator (+ its stats), not the uniform
-        # mean — the checkpoint must be the model whose merged_eval the
-        # history just reported; under a fault plan only agents alive at
-        # the end contribute
-        save(args.save_merged, merge_mod.merged_panel_tree(
-            state["panel"], spec, stats=state.get("merge_stat"),
-            live=alive_after(args.rounds - 1)))
+        save(args.save_merged, merged)
         print(f"saved {spec.merger}-merged model to", args.save_merged)
+    return {"history": history, "merged": merged}
 
 
 if __name__ == "__main__":

@@ -86,8 +86,7 @@ class Merger:
 
     # --------------------------------------------------------- merge
     def merge_row(self, panel, stats=None, weights=None, *, spec=None,
-                  use_pallas: bool = False, block_d: int = 512,
-                  interpret: bool = True, live=None):
+                  use_pallas: bool = False, block_d: int = 512, live=None):
         """One merged row {group: (D_g,) f32} from the (m, D) panel.
 
         ``live`` ((m,) bool) restricts every operator to the live agents'
@@ -95,7 +94,7 @@ class Merger:
         parameters and statistics are stale), exactly as if the operator
         ran on the m'-agent sub-panel."""
         return panel_mod.merged(panel, spec=spec, use_pallas=use_pallas,
-                                block_d=block_d, interpret=interpret,
+                                block_d=block_d,
                                 live=live)
 
 
@@ -115,7 +114,7 @@ def _constrain_row(row, spec):
             for k, v in row.items()}
 
 
-def _weighted_colmerge(panel, wpanel, spec, use_pallas, block_d, interpret):
+def _weighted_colmerge(panel, wpanel, spec, use_pallas, block_d):
     """Per-coordinate weighted column merge over all dtype groups —
     Pallas kernel single-device, XLA oracle under a sharded spec."""
     pallas = panel_mod._pallas_ok(use_pallas, spec)
@@ -123,8 +122,7 @@ def _weighted_colmerge(panel, wpanel, spec, use_pallas, block_d, interpret):
     for k, x in panel.items():
         if pallas:
             y = merge_kernels.weighted_colmerge(
-                x.astype(jnp.float32), wpanel[k], block_d=block_d,
-                interpret=interpret)
+                x.astype(jnp.float32), wpanel[k], block_d=block_d)
         else:
             y = ref_mod.weighted_colmerge_ref(x, wpanel[k])
         out[k] = y
@@ -150,7 +148,7 @@ class WeightedMerger(Merger):
                 mu = jnp.mean(x32, axis=0, keepdims=True)
             else:
                 lw = panel_mod._live_weights(live, x32.shape[0])
-                mu = jnp.tensordot(lw, x32, axes=1)[None]
+                mu = jnp.tensordot(lw, x32, axes=1, precision="highest")[None]
             d = d + jnp.sum(jnp.square(x32 - mu), axis=1)
         w = 1.0 / (d + self.eps)
         if live is not None:
@@ -158,8 +156,7 @@ class WeightedMerger(Merger):
         return w / jnp.sum(w)
 
     def merge_row(self, panel, stats=None, weights=None, *, spec=None,
-                  use_pallas: bool = False, block_d: int = 512,
-                  interpret: bool = True, live=None):
+                  use_pallas: bool = False, block_d: int = 512, live=None):
         if weights is None:
             w = self.agent_weights(panel, live=live)
         else:
@@ -167,7 +164,8 @@ class WeightedMerger(Merger):
             if live is not None:
                 w = w * live.astype(jnp.float32)
             w = w / jnp.sum(w)
-        row = {k: jnp.tensordot(w, x.astype(jnp.float32), axes=1)
+        row = {k: jnp.tensordot(w, x.astype(jnp.float32), axes=1,
+                                precision="highest")
                for k, x in panel.items()}
         return _constrain_row(row, spec)
 
@@ -203,8 +201,7 @@ class VarMerger(Merger):
         return {"traj_mu": mu, "traj_m2": m2}
 
     def merge_row(self, panel, stats=None, weights=None, *, spec=None,
-                  use_pallas: bool = False, block_d: int = 512,
-                  interpret: bool = True, live=None):
+                  use_pallas: bool = False, block_d: int = 512, live=None):
         if stats is None:
             raise ValueError(
                 "merger 'var' needs its trajectory stats panels "
@@ -219,8 +216,7 @@ class VarMerger(Merger):
             # zeroed row is excluded from both numerator and denominator
             lf = live.astype(jnp.float32)[:, None]
             w = {k: v * lf for k, v in w.items()}
-        return _weighted_colmerge(panel, w, spec, use_pallas, block_d,
-                                  interpret)
+        return _weighted_colmerge(panel, w, spec, use_pallas, block_d)
 
 
 class FisherMerger(Merger):
@@ -250,8 +246,7 @@ class FisherMerger(Merger):
             for k, g in gpan.items()}}
 
     def merge_row(self, panel, stats=None, weights=None, *, spec=None,
-                  use_pallas: bool = False, block_d: int = 512,
-                  interpret: bool = True, live=None):
+                  use_pallas: bool = False, block_d: int = 512, live=None):
         if stats is None:
             raise ValueError(
                 "merger 'fisher' needs its Fisher stats panel (stats=...);"
@@ -261,8 +256,7 @@ class FisherMerger(Merger):
         if live is not None:
             lf = live.astype(jnp.float32)[:, None]
             w = {k: v * lf for k, v in w.items()}
-        return _weighted_colmerge(panel, w, spec, use_pallas, block_d,
-                                  interpret)
+        return _weighted_colmerge(panel, w, spec, use_pallas, block_d)
 
 
 class TiesMerger(Merger):
@@ -280,8 +274,7 @@ class TiesMerger(Merger):
         self.trim = trim
 
     def merge_row(self, panel, stats=None, weights=None, *, spec=None,
-                  use_pallas: bool = False, block_d: int = 512,
-                  interpret: bool = True, live=None):
+                  use_pallas: bool = False, block_d: int = 512, live=None):
         pallas = panel_mod._pallas_ok(use_pallas, spec)
         out = {}
         for k, x in panel.items():
@@ -291,7 +284,7 @@ class TiesMerger(Merger):
                 tau = x32 - ref_row[None]
             else:
                 lw = panel_mod._live_weights(live, x32.shape[0])
-                ref_row = jnp.tensordot(lw, x32, axes=1)
+                ref_row = jnp.tensordot(lw, x32, axes=1, precision="highest")
                 # a zero tau row is inert through trim + election +
                 # agreeing-mean, so masking dead rows to zero makes the
                 # result exactly the live sub-panel's TIES merge
@@ -300,8 +293,7 @@ class TiesMerger(Merger):
             thresh = ref_mod.ties_thresh_ref(tau, self.trim)
             if pallas:
                 dev = merge_kernels.ties_colmerge(tau, thresh,
-                                                  block_d=block_d,
-                                                  interpret=interpret)
+                                                  block_d=block_d)
             else:
                 dev = ref_mod.ties_colmerge_ref(tau, thresh)
             out[k] = ref_row + dev
@@ -335,8 +327,7 @@ class SwaMerger(Merger):
             for k, x in panel.items()}}
 
     def merge_row(self, panel, stats=None, weights=None, *, spec=None,
-                  use_pallas: bool = False, block_d: int = 512,
-                  interpret: bool = True, live=None):
+                  use_pallas: bool = False, block_d: int = 512, live=None):
         if stats is None:
             raise ValueError(
                 "merger 'swa' needs its accumulator stats panel "
@@ -344,7 +335,7 @@ class SwaMerger(Merger):
                 "init_panel_state(merger='swa')")
         return panel_mod.merged(stats["swa"], spec=spec,
                                 use_pallas=use_pallas, block_d=block_d,
-                                interpret=interpret, live=live)
+                                live=live)
 
 
 MERGERS = {
@@ -394,8 +385,7 @@ def decode_stats(stats, spec):
 @scope("merge.panel")
 def merge_panel(panel, merger, *, stats=None, weights=None, spec=None,
                 wire_dtype=None, key=None, err=None,
-                use_pallas: bool = False, block_d: int = 512,
-                interpret: bool = True, live=None):
+                use_pallas: bool = False, block_d: int = 512, live=None):
     """One global merge ROUND through an operator: every agent transmits
     its panel through the spec's wire-codec policy (exactly like
     ``panel.global_merge`` — stochastic codecs take ``key=``, error
@@ -447,8 +437,7 @@ def merge_panel(panel, merger, *, stats=None, weights=None, spec=None,
                 backs[k] = wire_codec._storage_back(x.dtype)
                 continue
             xw, back, ne = codecs[k].encode(x, key=keys[k], err=e,
-                                            use_pallas=pallas,
-                                            interpret=interpret)
+                                            use_pallas=pallas)
             enc[k] = xw
             backs[k] = back
             if err is not None:
@@ -458,8 +447,7 @@ def merge_panel(panel, merger, *, stats=None, weights=None, spec=None,
         backs = {k: _identity_back for k in panel}
         new_err = err
     row = merger.merge_row(enc, stats=stats, weights=weights, spec=spec,
-                           use_pallas=use_pallas, block_d=block_d,
-                           interpret=interpret, live=live)
+                           use_pallas=use_pallas, block_d=block_d, live=live)
     lcol = None if live is None else live[:, None]
     mixed = {}
     for k, x in panel.items():

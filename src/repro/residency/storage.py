@@ -96,18 +96,15 @@ class Storage:
         """Deterministic encode (state build / RESYNC re-init)."""
         return x
 
-    def write(self, x, *, key=None, use_pallas: bool = False,
-              interpret: bool = True):
+    def write(self, x, *, key=None, use_pallas: bool = False):
         """Hot-path encode of an f32 panel into its stored rep."""
         return x
 
-    def read(self, stored, *, use_pallas: bool = False,
-             interpret: bool = True):
+    def read(self, stored, *, use_pallas: bool = False):
         """Decode a stored rep back to the f32 compute view."""
         return stored
 
-    def maybe_read(self, v, *, use_pallas: bool = False,
-                   interpret: bool = True):
+    def maybe_read(self, v, *, use_pallas: bool = False):
         """``read`` that tolerates an ALREADY-DECODED f32 leaf — the
         out-of-engine entry point (merging.merge_panel's stat reads may
         see either the stored rep or the engine's decoded view)."""
@@ -147,16 +144,13 @@ class Bf16Storage(Storage):
     def init(self, x):
         return x.astype(jnp.bfloat16)
 
-    def write(self, x, *, key=None, use_pallas: bool = False,
-              interpret: bool = True):
+    def write(self, x, *, key=None, use_pallas: bool = False):
         return x.astype(jnp.bfloat16)
 
-    def read(self, stored, *, use_pallas: bool = False,
-             interpret: bool = True):
+    def read(self, stored, *, use_pallas: bool = False):
         return stored.astype(jnp.float32)
 
-    def maybe_read(self, v, *, use_pallas: bool = False,
-                   interpret: bool = True):
+    def maybe_read(self, v, *, use_pallas: bool = False):
         # state panels are f32 by construction, so a bf16 leaf can only
         # be this storage's rep; an already-decoded f32 view passes
         return v.astype(jnp.float32) if v.dtype == jnp.bfloat16 else v
@@ -216,16 +210,16 @@ class Int8Storage(Storage):
             return ref_mod.int8_scale_ref(x32)
         return ref_mod.int8_group_scale_ref(x32, self.group)
 
-    def _quantize(self, x, u, use_pallas, interpret):
+    def _quantize(self, x, u, use_pallas):
         x32 = self.transform_fwd(x.astype(jnp.float32))
         scale = self._scale(x32)
         if use_pallas:
             if self.group is None:
                 q, _ = wire_quant.quantize_int8_panel(
-                    x32, scale, u, interpret=interpret)
+                    x32, scale, u)
             else:
                 q, _ = wire_quant.quantize_int8_grouped_panel(
-                    x32, scale, u, group=self.group, interpret=interpret)
+                    x32, scale, u, group=self.group)
         elif self.group is None:
             q = ref_mod.quantize_int8_ref(x32, scale, u)
         else:
@@ -234,39 +228,35 @@ class Int8Storage(Storage):
         return {"q": q, "scale": scale}
 
     def init(self, x):
-        return self._quantize(x, None, False, True)
+        return self._quantize(x, None, False)
 
-    def write(self, x, *, key=None, use_pallas: bool = False,
-              interpret: bool = True):
+    def write(self, x, *, key=None, use_pallas: bool = False):
         if key is None:
             raise ValueError(
                 f"storage '{self.name}' uses stochastic rounding and "
                 "needs an explicit key= (use init() for the "
                 "deterministic encode)")
         u = _uniform(key, x.shape)
-        return self._quantize(x, u, use_pallas, interpret)
+        return self._quantize(x, u, use_pallas)
 
-    def read(self, stored, *, use_pallas: bool = False,
-             interpret: bool = True):
+    def read(self, stored, *, use_pallas: bool = False):
         q, scale = stored["q"], stored["scale"]
         if use_pallas:
             if self.group is None:
                 y = wire_quant.dequantize_int8_panel(
-                    q, scale, interpret=interpret)
+                    q, scale)
             else:
                 y = wire_quant.dequantize_int8_grouped_panel(
-                    q, scale, group=self.group, interpret=interpret)
+                    q, scale, group=self.group)
         elif self.group is None:
             y = ref_mod.dequantize_int8_ref(q, scale)
         else:
             y = ref_mod.dequantize_int8_grouped_ref(q, scale, self.group)
         return self.transform_inv(y)
 
-    def maybe_read(self, v, *, use_pallas: bool = False,
-                   interpret: bool = True):
+    def maybe_read(self, v, *, use_pallas: bool = False):
         if isinstance(v, dict):
-            return self.read(v, use_pallas=use_pallas,
-                             interpret=interpret)
+            return self.read(v, use_pallas=use_pallas)
         return v
 
     def zero_like(self, stored):

@@ -63,7 +63,7 @@ def agent_dist_to_mean(panel, live=None):
     total = jnp.zeros((m,), jnp.float32)
     for x in panel.values():
         x32 = x.astype(jnp.float32)
-        mean = jnp.tensordot(w, x32, axes=1)
+        mean = jnp.tensordot(w, x32, axes=1, precision="highest")
         total = total + jnp.sum(jnp.square(x32 - mean[None]), axis=1)
     return jnp.sqrt(total)
 

@@ -7,16 +7,11 @@ Three layers, all safe to leave in hot code:
   instead of ``dot_general.127``. Zero runtime cost (trace-time only).
 * :func:`annotate` — ``jax.profiler.TraceAnnotation``: a HOST-side span
   on the profiler timeline (scheduler work: admit, step, checkpoint).
-  Nullcontext when the profiler backend is unavailable.
 * :func:`profile_trace` — capture a jax profiler trace into a logdir
-  (``--profile`` in the launchers). Degrades to a warning + no-op if the
-  profiler cannot start in this environment (it must never take down a
-  training run).
+  (``--profile`` in the launchers). A profiler that cannot start or stop
+  raises: a run asked to trace never finishes without its trace.
 """
 from __future__ import annotations
-
-import contextlib
-import warnings
 
 import jax
 
@@ -27,20 +22,16 @@ def scope(name: str):
 
 
 def annotate(name: str, **kwargs):
-    """Host-side profiler span; no-op where TraceAnnotation is missing."""
-    try:
-        return jax.profiler.TraceAnnotation(name, **kwargs)
-    except Exception:
-        return contextlib.nullcontext()
+    """Host-side profiler span."""
+    return jax.profiler.TraceAnnotation(name, **kwargs)
 
 
 class profile_trace:
     """Context manager capturing a jax profiler trace into ``logdir``.
 
     ``enabled=False`` makes it a no-op (so call sites can pass the CLI
-    flag straight through); a profiler that fails to start or stop only
-    warns. ``bool(ctx)`` inside the block reports whether a trace is
-    actually being captured."""
+    flag straight through). ``bool(ctx)`` inside the block reports
+    whether a trace is being captured."""
 
     def __init__(self, logdir: str, enabled: bool = True):
         self.logdir = logdir
@@ -53,23 +44,15 @@ class profile_trace:
     def start(self):
         if not self.enabled or self.active:
             return self
-        try:
-            jax.profiler.start_trace(self.logdir)
-            self.active = True
-        except Exception as e:  # missing backend, busy profiler, ...
-            warnings.warn(f"jax profiler trace could not start: {e}",
-                          RuntimeWarning)
+        jax.profiler.start_trace(self.logdir)
+        self.active = True
         return self
 
     def stop(self):
         if not self.active:
             return
         self.active = False
-        try:
-            jax.profiler.stop_trace()
-        except Exception as e:
-            warnings.warn(f"jax profiler trace could not stop: {e}",
-                          RuntimeWarning)
+        jax.profiler.stop_trace()
 
     def __enter__(self):
         return self.start()

@@ -4,8 +4,7 @@ A codec controls how one dtype group's (m, D_g) panel travels during a
 communication op without changing the storage dtype of the state. The
 single entry point mirrors (and generalizes) the old ``panel._wire`` cast:
 
-    xw, back, new_err = codec.encode(x, key=..., err=...,
-                                     use_pallas=..., interpret=...)
+    xw, back, new_err = codec.encode(x, key=..., err=..., use_pallas=...)
 
 ``xw`` is the array the mixing math runs on — the receive-side view of
 the payload (for ``int8``/``int4`` that is the dequantized panel;
@@ -31,9 +30,10 @@ Codecs (``CODECS`` registry):
   (x + e) - dequant(quant(x + e)) is returned for the caller to carry —
   the panel engine keeps it as an extra donated (m, D) f32 panel.
 * ``int4`` — packed nibbles on the wire (TWO quantized values per byte,
-  ``kernels/ref.py:pack_int4_ref`` layout: even column low nibble, odd
-  column high) against GROUPED symmetric scales — one f32 amax/7 scale
-  per row per ``group`` (default 128) columns, so outlier columns only
+  ``kernels/ref.py:pack_int4_ref`` layout: the first half of the columns
+  in the low nibbles, the second half in the high ones) against GROUPED
+  symmetric scales — one f32 amax/7 scale per row per ``group``
+  (default 128) columns, so outlier columns only
   poison their own group instead of the whole row. Same key-driven
   stochastic rounding as int8; ``int4_ef`` adds the same error feedback.
   ~8x fewer payload bytes than f32 (plus 4/group scale overhead). The
@@ -138,8 +138,7 @@ class F32Codec(Codec):
     def payload_bytes(self, rows: int, width: int, dtype) -> int:
         return rows * width * jnp.dtype(dtype).itemsize
 
-    def encode(self, x, key=None, err=None, use_pallas: bool = False,
-               interpret: bool = True):
+    def encode(self, x, key=None, err=None, use_pallas: bool = False):
         return x, _identity, err
 
     def wire_payload(self, x, key=None, err=None):
@@ -158,8 +157,7 @@ class DtypeCodec(Codec):
     def payload_bytes(self, rows: int, width: int, dtype) -> int:
         return rows * width * self.wire_dtype.itemsize
 
-    def encode(self, x, key=None, err=None, use_pallas: bool = False,
-               interpret: bool = True):
+    def encode(self, x, key=None, err=None, use_pallas: bool = False):
         if x.dtype == self.wire_dtype:
             return x, _identity, err
         return (x.astype(self.wire_dtype),
@@ -227,27 +225,24 @@ class Int8Codec(Codec):
             x32 = x32 + err
         return x32
 
-    def _quantize(self, x32, key, use_pallas: bool, interpret: bool):
+    def _quantize(self, x32, key, use_pallas: bool):
         u = None
         if self.stochastic:
             _require_key(self, key)
             u = _uniform(key, x32.shape)
         scale = ref_mod.int8_scale_ref(x32)
         if use_pallas:
-            q, _ = wire_quant.quantize_int8_panel(x32, scale, u,
-                                                  interpret=interpret)
+            q, _ = wire_quant.quantize_int8_panel(x32, scale, u)
         else:
             q = ref_mod.quantize_int8_ref(x32, scale, u)
         return q, scale
 
-    def encode(self, x, key=None, err=None, use_pallas: bool = False,
-               interpret: bool = True):
+    def encode(self, x, key=None, err=None, use_pallas: bool = False):
         _require_err(self, err)
         x32 = self._carry_in(x, err)
-        q, scale = self._quantize(x32, key, use_pallas, interpret)
+        q, scale = self._quantize(x32, key, use_pallas)
         if use_pallas:
-            xhat32 = wire_quant.dequantize_int8_panel(q, scale,
-                                                      interpret=interpret)
+            xhat32 = wire_quant.dequantize_int8_panel(q, scale)
         else:
             xhat32 = ref_mod.dequantize_int8_ref(q, scale)
         new_err = (x32 - xhat32) if (self.error_feedback
@@ -259,7 +254,7 @@ class Int8Codec(Codec):
     def wire_payload(self, x, key=None, err=None):
         _require_err(self, err)  # same contract as encode: never
         # silently measure Q(x) when the run would transmit Q(x + e)
-        q, scale = self._quantize(self._carry_in(x, err), key, False, True)
+        q, scale = self._quantize(self._carry_in(x, err), key, False)
         return [q], [scale]
 
 
@@ -294,7 +289,7 @@ class Int4Codec(Codec):
 
     _carry_in = Int8Codec._carry_in
 
-    def _quantize(self, x32, key, use_pallas: bool, interpret: bool):
+    def _quantize(self, x32, key, use_pallas: bool):
         u = None
         if self.stochastic:
             _require_key(self, key)
@@ -302,28 +297,25 @@ class Int4Codec(Codec):
         scale = ref_mod.int4_group_scale_ref(x32, self.group)
         if use_pallas:
             q, _ = wire_quant.quantize_int4_panel(x32, scale, u,
-                                                  group=self.group,
-                                                  interpret=interpret)
+                                                  group=self.group)
         else:
             q = ref_mod.quantize_int4_ref(x32, scale, u, self.group)
         return q, scale
 
-    def encode(self, x, key=None, err=None, use_pallas: bool = False,
-               interpret: bool = True):
+    def encode(self, x, key=None, err=None, use_pallas: bool = False):
         _require_err(self, err)
         x32 = self._carry_in(x, err)
         D = x.shape[1]
-        q, scale = self._quantize(x32, key, use_pallas, interpret)
+        q, scale = self._quantize(x32, key, use_pallas)
         # the mixing view is rebuilt from the packed WIRE bytes — the
         # pack/unpack pair is an exact inverse for values in [-7, 7], so
         # this costs two cheap byte kernels and guarantees the math runs
         # on exactly what a receiver would reconstruct
         if use_pallas:
-            packed = wire_quant.pack_int4_panel(q, interpret=interpret)
-            qw = wire_quant.unpack_int4_panel(packed, D,
-                                              interpret=interpret)
+            packed = wire_quant.pack_int4_panel(q)
+            qw = wire_quant.unpack_int4_panel(packed, D)
             xhat32 = wire_quant.dequantize_int4_panel(
-                qw, scale, group=self.group, interpret=interpret)
+                qw, scale, group=self.group)
         else:
             packed = ref_mod.pack_int4_ref(q)
             qw = ref_mod.unpack_int4_ref(packed, D)
@@ -336,7 +328,7 @@ class Int4Codec(Codec):
 
     def wire_payload(self, x, key=None, err=None):
         _require_err(self, err)  # as in Int8Codec.wire_payload
-        q, scale = self._quantize(self._carry_in(x, err), key, False, True)
+        q, scale = self._quantize(self._carry_in(x, err), key, False)
         return [ref_mod.pack_int4_ref(q)], [scale]
 
 
@@ -423,15 +415,13 @@ class TopKCodec(Codec):
         kk = max(1, int(sub.shape[1] * self.density))
         return jax.lax.top_k(sub, kk)[0][:, -1:]
 
-    def encode(self, x, key=None, err=None, use_pallas: bool = False,
-               interpret: bool = True):
+    def encode(self, x, key=None, err=None, use_pallas: bool = False):
         _require_err(self, err)
         x32 = x.astype(jnp.float32)
         innov = x32 - err
         thresh = self._threshold(innov)
         if use_pallas:
-            q = wire_quant.sparsify_topk_panel(innov, thresh,
-                                               interpret=interpret)
+            q = wire_quant.sparsify_topk_panel(innov, thresh)
         else:
             q = ref_mod.sparsify_topk_ref(innov, thresh)
         mirror = err + q

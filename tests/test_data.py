@@ -54,6 +54,22 @@ def test_lm_domain_skew_changes_statistics():
     assert tv > 0.3  # clearly different token distributions
 
 
+def test_lm_large_vocab_is_structured_and_domain_skewed():
+    """Above DENSE_MAX_VOCAB no transition table is built; each domain's
+    streams still sit mostly in its own token subset."""
+    V, D = 50304, 8
+    lm = SyntheticLM(vocab=V, num_domains=D, seed=0)
+    assert lm._trans is None
+    rng = np.random.default_rng(0)
+    for d in (0, D - 1):
+        toks = lm.sample(np.eye(D)[d], 8, 128, rng)
+        assert toks.shape == (8, 129) and toks.dtype == np.int32
+        assert (toks >= 0).all() and (toks < V).all()
+        lo, hi = d * V // D, (d + 1) * V // D
+        inside = np.mean((toks[:, 1:] >= lo) & (toks[:, 1:] < hi))
+        assert inside > 0.8  # the dense tables' expected mass is 0.92
+
+
 def test_lm_agent_batches_structure():
     lm = SyntheticLM(vocab=32, num_domains=4)
     mix = lm.domain_mixtures(3, 0.1)

@@ -23,8 +23,8 @@ SCRIPT = textwrap.dedent("""
     cfg = cfg.replace(dist=dataclasses.replace(cfg.dist, scan_layers=False,
                                                agents_per_pod=2))
     model = build_model(cfg)
-    mesh = jax.make_mesh((1, 2, 2, 2), ("pod", "agent", "fsdp", "model"),
-                         devices=jax.devices())
+    from repro.launch.mesh import make_debug_mesh
+    mesh = make_debug_mesh(agents=2, fsdp=2, model=2)
     m = 2
     opt = make_optimizer("adamw", 1e-3)
     key = jax.random.PRNGKey(0)
