@@ -1,0 +1,2 @@
+"""The chip benchmark: ``python3 bench/run.py --workload <name> --seed <n>
+--seconds <s> --trace <0|1>`` runs one cell of ``BENCHMARK.json`` once."""

@@ -1,0 +1,15 @@
+"""serve.prefill_mfu (%): the FLOPs of the window's prefills over the device
+time of the prefill programs, over the bf16 peak. Moves
+serve_ttft_p50_ms."""
+from bench import counts
+
+
+def read(ctx):
+    cfg = ctx["cfg"]
+    t = max(d["module_ns"]["prefill"]
+            for d in ctx["reduced"]["devices"].values())
+    flops = sum(counts.prefill_flops(cfg, n)
+                for n in ctx["counts"]["prefill_lengths"])
+    if not t or not flops:
+        return None
+    return 100.0 * flops / (t / 1e9) / ctx["peaks"]["bf16_flops_per_s"]
