@@ -781,17 +781,22 @@ def make_panel_segment(loss_fn: Callable, optimizer: Optimizer,
             # agent rows live on different devices: one vmapped program
             params = panel_mod.from_panel(pan, spec,
                                           leaf_shardings=param_shardings)
-            grads, losses = jax.vmap(one)(params, batch, rngs)
+            with scope("dsgd.fwd_bwd"):
+                grads, losses = jax.vmap(one)(params, batch, rngs)
             return panel_mod.to_panel(grads, spec), losses
 
         # one device holds every row: agents run one after another. A
         # vmap over the (m, D) panel fuses the panel<->leaf relayouts into
         # the forward/backward, and the TPU compiler's code then grows
         # with D: olmo-1b at 1 layer, m=4 compiled to 735 MB of code in
-        # 776 s with 30 GB of host memory, against 30 MB in 16 s this way
+        # 776 s with 30 GB of host memory, against 30 MB in 16 s this way.
+        # Only the model's forward and backward sit under dsgd.fwd_bwd; the
+        # row's relayout and lax.map's stacking stay outside it
         def agent(xs):
             row, b, r = xs
-            g, l = one(panel_mod.from_panel(row, spec), b, r)
+            params = panel_mod.from_panel(row, spec)
+            with scope("dsgd.fwd_bwd"):
+                g, l = one(params, b, r)
             gp = panel_mod.to_panel(jax.tree.map(lambda x: x[None], g), spec)
             return {k: v[0] for k, v in gp.items()}, l
 

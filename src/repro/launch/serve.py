@@ -146,12 +146,20 @@ def main(argv=None):
         "serve_end", requests=len(out), tokens=n_tok,
         ticks=snap["ticks"], occupancy=snap["occupancy"])), flush=True)
     lat = snap["latency"]
+    recs = snap["requests"]
     print(f"  {n_tok / dt:.1f} tok/s | "
           f"ttft p50/p99 {lat['ttft_s']['p50_s'] * 1e3:.1f}/"
           f"{lat['ttft_s']['p99_s'] * 1e3:.1f} ms | queue p50 "
           f"{lat['queue_wait_s']['p50_s'] * 1e3:.1f} ms | decode step "
           f"p50 {lat['decode_step_s']['p50_s'] * 1e3:.1f} ms | per-token "
           f"p50 {lat['per_token_s']['p50_s'] * 1e3:.1f} ms")
+    # exact medians over the engine's per-request records (the histograms
+    # above interpolate inside 33% buckets)
+    print(f"  per request (median of {len(recs)}): queue wait "
+          f"{np.median([r['admit'] - r['arrival'] for r in recs]) * 1e3:.1f}"
+          f" ms | admission to first token "
+          f"{np.median([r['first'] - r['admit'] for r in recs]) * 1e3:.1f}"
+          f" ms")
     log.emit_op("serve_latency", **{k: lat[k] for k in lat})
     log.close()
     for rid in sorted(out)[:2]:

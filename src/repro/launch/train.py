@@ -226,7 +226,10 @@ def main(argv=None):
     ap.add_argument("--profile", default="",
                     help="capture a jax profiler trace of the training "
                          "loop into this logdir (view with tensorboard/"
-                         "xprof); a profiler that cannot start is an error")
+                         "xprof), with host spans train.sample, "
+                         "train.dispatch, train.fetch, train.eval and "
+                         "train.checkpoint; a profiler that cannot start is "
+                         "an error")
     args = ap.parse_args(argv)
 
     cfg = preset_config(get_config(args.arch), args.preset)
@@ -450,20 +453,25 @@ def main(argv=None):
         glob = jnp.asarray(glob)
         live = (jnp.asarray(np.stack(lives), jnp.int32)
                 if plan is not None else None)
-        batches = sample_segment_batches(lm, mixtures, S, args.local_steps,
-                                         args.batch, args.seq, rng_np)
-        if pad:
-            batches = {k: jnp.concatenate(
-                [v, jnp.zeros((pad,) + v.shape[1:], v.dtype)]) for k, v in
-                batches.items()}
-        if batch_sharding is not None:
-            batches = {k: jax.device_put(v, batch_sharding)
-                       for k, v in batches.items()}
+        with telemetry.annotate("train.sample"):
+            batches = sample_segment_batches(lm, mixtures, S,
+                                             args.local_steps, args.batch,
+                                             args.seq, rng_np)
+            if pad:
+                batches = {k: jnp.concatenate(
+                    [v, jnp.zeros((pad,) + v.shape[1:], v.dtype)])
+                    for k, v in batches.items()}
+            if batch_sharding is not None:
+                batches = {k: jax.device_put(v, batch_sharding)
+                           for k, v in batches.items()}
         active = jnp.asarray([True] * S + [False] * pad)
         key, k = jax.random.split(key)
         seg_t0 = time.perf_counter()
-        state, mets = segment_fn(state, batches, Ws, k, active, glob, live)
-        mets = jax.device_get(mets)  # ONE transfer for the whole segment
+        with telemetry.annotate("train.dispatch"):
+            state, mets = segment_fn(state, batches, Ws, k, active, glob,
+                                     live)
+        with telemetry.annotate("train.fetch"):
+            mets = jax.device_get(mets)  # ONE transfer for the segment
         mets = {k: v[:S] for k, v in mets.items()}
         monitor = {"grad_norm": float(mets["grad_norm"][-1]),
                    "consensus": float(mets["consensus"][-1])}
@@ -472,12 +480,13 @@ def main(argv=None):
         do_eval = (ev == 0 or (t + S) % ev == 0 or t + S == args.rounds)
         merged_l = local_l = None
         if do_eval:
-            lv_now = alive_after(t + S - 1)
-            merged_l = float(eval_merged(state["panel"],
-                                         state.get("merge_stat"),
-                                         eval_batch, lv_now))
-            local_l = float(eval_local(state["panel"], eval_batch,
-                                       lv_now))
+            with telemetry.annotate("train.eval"):
+                lv_now = alive_after(t + S - 1)
+                merged_l = float(eval_merged(state["panel"],
+                                             state.get("merge_stat"),
+                                             eval_batch, lv_now))
+                local_l = float(eval_local(state["panel"], eval_batch,
+                                           lv_now))
         rev = None
         for s in range(S):
             r = t + s
@@ -522,12 +531,13 @@ def main(argv=None):
             # the next segment is free to donate the live state.
             # events_seq checkpoints the deterministic stream's position —
             # the truncate-on-resume cursor
-            ckpt.save(t, {"state": state, "key": key}, block=False, meta={
-                "round": t, "segments": seg_idx, "comm_cost": comm_cost,
-                "monitor": monitor, "history": history,
-                "data_rng": rng_np.bit_generator.state,
-                "sched_rng": sched.rng.bit_generator.state,
-                "events_seq": log.seq})
+            with telemetry.annotate("train.checkpoint"):
+                ckpt.save(t, {"state": state, "key": key}, block=False, meta={
+                    "round": t, "segments": seg_idx, "comm_cost": comm_cost,
+                    "monitor": monitor, "history": history,
+                    "data_rng": rng_np.bit_generator.state,
+                    "sched_rng": sched.rng.bit_generator.state,
+                    "events_seq": log.seq})
         if args.die_after_segments and seg_idx >= args.die_after_segments:
             if ckpt is not None:
                 ckpt.wait()

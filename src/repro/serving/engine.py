@@ -34,7 +34,7 @@ from __future__ import annotations
 
 import collections
 import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from functools import partial
 from typing import Any, Callable, Dict, List, Optional
 
@@ -183,22 +183,32 @@ def generate(model, params, batch, max_new: int, *, temperature: float = 0.0,
 @dataclass
 class Request:
     """One serving request: prompt ids + optional multimodal extras
-    (``patch_embeds`` (P, d) / ``frame_embeds`` (S, d), unbatched)."""
+    (``patch_embeds`` (P, d) / ``frame_embeds`` (S, d), unbatched).
+
+    ``arrival`` is the ``time.perf_counter()`` time at which the request
+    reached the server; ``submit()`` stamps it when left None. Queue wait
+    and TTFT run from it, so a caller that submits late (after a step it
+    was blocked on) passes the true arrival."""
     rid: Any
     tokens: np.ndarray
     max_new: int = 16
     extras: Dict[str, Any] = field(default_factory=dict)
+    arrival: Optional[float] = None
+
+
+# admitted requests whose record the engine keeps (oldest dropped first)
+RECORD_CAP = 4096
 
 
 class _Slot:
-    __slots__ = ("req", "pos", "last", "out", "t_first")
+    __slots__ = ("req", "pos", "last", "out", "rec")
 
-    def __init__(self, req, pos, first_token, t_first=0.0):
+    def __init__(self, req, pos, first_token, rec):
         self.req = req
         self.pos = pos  # absolute position of the NEXT token to feed
         self.last = first_token
         self.out = [first_token]
-        self.t_first = t_first  # perf_counter at first token (TTFT mark)
+        self.rec = rec  # the request's record (see ServingEngine)
 
 
 class ServingEngine:
@@ -211,17 +221,26 @@ class ServingEngine:
     stays on device.
 
     **Telemetry.** The engine keeps its own fixed-bucket latency
-    histograms (:mod:`repro.telemetry.latency`): ``ttft_s`` (submit →
-    first token, covers queue + prefill), ``queue_wait_s`` (submit →
+    histograms (:mod:`repro.telemetry.latency`): ``ttft_s`` (arrival →
+    first token, covers queue + prefill), ``queue_wait_s`` (arrival →
     admission), ``decode_step_s`` (one jitted step incl. the (C,) token
     fetch) and ``per_token_s`` (a retired request's steady-state decode
-    rate: time from its first token to retirement over tokens-1).
-    :meth:`snapshot` exports counters + occupancy + histogram summaries;
-    :meth:`reset` zeroes them WITHOUT touching live slots or queued work,
-    so callers can discard warmup/compile ticks (serve_bench, the serve
-    CLI). Passing ``events=`` an :class:`repro.telemetry.EventLog` emits
-    typed ``request_submit``/``request_admit``/``request_retire``
-    records.
+    rate: time from its first token to retirement over tokens-1). It also
+    keeps one record per admitted request (the newest ``RECORD_CAP``):
+    ``rid``, ``arrival``, ``admit`` (admission start), ``first`` (first
+    token on the host), ``retire`` (None while live) and ``tokens``, all
+    ``perf_counter`` times. :meth:`snapshot` exports counters + occupancy
+    + histogram summaries + the records; :meth:`reset` zeroes them
+    WITHOUT touching live slots or queued work, so callers can discard
+    warmup/compile ticks (serve_bench, the serve CLI). Passing ``events=``
+    an :class:`repro.telemetry.EventLog` emits typed
+    ``request_submit``/``request_admit``/``request_retire`` records.
+
+    Host spans (:func:`repro.telemetry.annotate`) cover all of the
+    engine's host time: ``serve.step`` a whole :meth:`step`, with
+    ``serve.fetch`` its (C,) token fetch inside; ``serve.admit`` one
+    request's whole admission, with ``serve.first_token`` (the host-side
+    sample and its fetch) inside.
     """
 
     def __init__(self, model, params, *, max_concurrency: int = 4,
@@ -260,28 +279,32 @@ class ServingEngine:
                       "admitted": 0, "retired": 0, "prefill_tokens": 0}
         self.hists = histogram_set(
             ("ttft_s", "queue_wait_s", "decode_step_s", "per_token_s"))
-        self._t_submit: Dict[Any, float] = {}
+        self.records: collections.deque = collections.deque(
+            maxlen=RECORD_CAP)
         self.events = events
 
     # ------------------------------------------------------------ telemetry
     def snapshot(self) -> Dict[str, Any]:
         """Stats snapshot: counters + occupancy + latency summaries (and
-        the raw sparse histograms, for cross-engine aggregation)."""
+        the raw sparse histograms, for cross-engine aggregation) + a copy
+        of the per-request records."""
         return {**self.stats, "occupancy": self.occupancy,
                 "latency": {k: h.summary() for k, h in self.hists.items()},
                 "histograms": {k: h.to_dict() for k, h in
-                               self.hists.items()}}
+                               self.hists.items()},
+                "requests": [dict(r) for r in self.records]}
 
     def reset(self):
-        """Zero counters and histograms; slots, queue and results are NOT
-        touched — call after warmup so occupancy/latency cover only the
-        measured window (the old dict was never resettable, so occupancy
-        averaged over compile ticks)."""
+        """Zero counters and histograms and drop the request records;
+        slots, queue and results are NOT touched — call after warmup so
+        occupancy/latency cover only the measured window (the old dict was
+        never resettable, so occupancy averaged over compile ticks)."""
         for k in ("ticks", "live_slot_ticks", "admitted", "retired",
                   "prefill_tokens"):
             self.stats[k] = 0
         for h in self.hists.values():
             h.reset()
+        self.records.clear()
 
     # ----------------------------------------------------- slot primitives
     def free_slots(self) -> List[int]:
@@ -302,7 +325,8 @@ class ServingEngine:
 
     # ------------------------------------------------------------ schedule
     def submit(self, req: Request):
-        self._t_submit[req.rid] = time.perf_counter()
+        if req.arrival is None:  # stamp a copy: the caller's stays as given
+            req = replace(req, arrival=time.perf_counter())
         self.queue.append(req)
         if self.events is not None:
             self.events.emit(
@@ -311,9 +335,10 @@ class ServingEngine:
                 max_new=int(req.max_new))
 
     def _sample_host(self, logits) -> int:
-        self._rng, k = jax.random.split(self._rng)
-        return int(sample_token(logits, k, self.temperature,
-                                vocab_size=self.cfg.vocab_size)[0])
+        with annotate("serve.first_token"):
+            self._rng, k = jax.random.split(self._rng)
+            return int(sample_token(logits, k, self.temperature,
+                                    vocab_size=self.cfg.vocab_size)[0])
 
     def _retire_if_done(self, slot: int):
         s = self._slots[slot]
@@ -322,9 +347,11 @@ class ServingEngine:
             self.results[s.req.rid] = np.asarray(s.out, np.int32)
             self._slots[slot] = None
             self.stats["retired"] += 1
+            s.rec["retire"] = time.perf_counter()
+            s.rec["tokens"] = len(s.out)
             if len(s.out) > 1:
                 self.hists["per_token_s"].record(
-                    (time.perf_counter() - s.t_first) / (len(s.out) - 1))
+                    (s.rec["retire"] - s.rec["first"]) / (len(s.out) - 1))
             if self.events is not None:
                 self.events.emit("request_retire", rid=s.req.rid,
                                  slot=slot, tick=self.stats["ticks"],
@@ -336,67 +363,73 @@ class ServingEngine:
         for slot in self.free_slots():
             if not self.queue:
                 break
-            req = self.queue.popleft()
-            t_sub = self._t_submit.pop(req.rid, None)
-            if t_sub is not None:
-                self.hists["queue_wait_s"].record(
-                    time.perf_counter() - t_sub)
-            prompt = np.asarray(req.tokens, np.int32).reshape(-1)
-            batch = {"tokens": jnp.asarray(prompt[None])}
-            for key, val in req.extras.items():
-                batch[key] = jnp.asarray(val)[None]
-            prefix = (batch["patch_embeds"].shape[1]
-                      if "patch_embeds" in batch else 0)
-            start = prefix + prompt.shape[0]
-            if start + req.max_new > self.max_len:
-                raise ValueError(
-                    f"request {req.rid!r}: prefix+prompt+max_new = "
-                    f"{start + req.max_new} exceeds max_len={self.max_len}")
             with annotate("serve.admit"):
-                logits, row = self._prefill(self.params, batch)
-                self.insert(row, slot)
-                first = self._sample_host(logits)
-            t_first = time.perf_counter()
-            if t_sub is not None:
-                self.hists["ttft_s"].record(t_first - t_sub)
-            self._slots[slot] = _Slot(req, start, first, t_first)
-            self.stats["admitted"] += 1
-            self.stats["prefill_tokens"] += int(start)
+                self._admit_one(slot)
             n += 1
-            if self.events is not None:
-                self.events.emit("request_admit", rid=req.rid, slot=slot,
-                                 tick=self.stats["ticks"])
-            self._retire_if_done(slot)  # max_new == 1 / instant EOS
         return n
+
+    def _admit_one(self, slot: int):
+        """Admit the head of the queue into ``slot``: prefill, insert, the
+        first token sampled on the host, and the request's record."""
+        t_admit = time.perf_counter()
+        req = self.queue.popleft()
+        self.hists["queue_wait_s"].record(t_admit - req.arrival)
+        prompt = np.asarray(req.tokens, np.int32).reshape(-1)
+        batch = {"tokens": jnp.asarray(prompt[None])}
+        for key, val in req.extras.items():
+            batch[key] = jnp.asarray(val)[None]
+        prefix = (batch["patch_embeds"].shape[1]
+                  if "patch_embeds" in batch else 0)
+        start = prefix + prompt.shape[0]
+        if start + req.max_new > self.max_len:
+            raise ValueError(
+                f"request {req.rid!r}: prefix+prompt+max_new = "
+                f"{start + req.max_new} exceeds max_len={self.max_len}")
+        logits, row = self._prefill(self.params, batch)
+        self.insert(row, slot)
+        first = self._sample_host(logits)
+        t_first = time.perf_counter()
+        self.hists["ttft_s"].record(t_first - req.arrival)
+        rec = {"rid": req.rid, "arrival": req.arrival, "admit": t_admit,
+               "first": t_first, "retire": None, "tokens": 1}
+        self.records.append(rec)
+        self._slots[slot] = _Slot(req, start, first, rec)
+        self.stats["admitted"] += 1
+        self.stats["prefill_tokens"] += int(start)
+        if self.events is not None:
+            self.events.emit("request_admit", rid=req.rid, slot=slot,
+                             tick=self.stats["ticks"])
+        self._retire_if_done(slot)  # max_new == 1 / instant EOS
 
     def step(self):
         """One decode step over ALL slots. Returns [(rid, token), ...] for
         the live slots (in slot order)."""
-        live = self.live_slots()
-        tokens = np.full((self.C,), self.pad_id, np.int32)
-        index = np.zeros((self.C,), np.int32)
-        for i in live:
-            tokens[i] = self._slots[i].last
-            index[i] = self._slots[i].pos
-        self._rng, k = jax.random.split(self._rng)
-        t0 = time.perf_counter()
         with annotate("serve.step"):
+            live = self.live_slots()
+            tokens = np.full((self.C,), self.pad_id, np.int32)
+            index = np.zeros((self.C,), np.int32)
+            for i in live:
+                tokens[i] = self._slots[i].last
+                index[i] = self._slots[i].pos
+            self._rng, k = jax.random.split(self._rng)
+            t0 = time.perf_counter()
             self.caches, tok = self._step_fn(self.params, self.caches,
                                              jnp.asarray(tokens),
                                              jnp.asarray(index), k)
-            tok = np.asarray(tok)  # the ONE host fetch per tick: (C,) int32
-        self.hists["decode_step_s"].record(time.perf_counter() - t0)
-        self.stats["ticks"] += 1
-        self.stats["live_slot_ticks"] += len(live)
-        emitted = []
-        for i in live:
-            s = self._slots[i]
-            s.pos += 1
-            s.last = int(tok[i])
-            s.out.append(s.last)
-            emitted.append((s.req.rid, s.last))
-            self._retire_if_done(i)
-        return emitted
+            with annotate("serve.fetch"):
+                tok = np.asarray(tok)  # the ONE host fetch per tick: (C,)
+            self.hists["decode_step_s"].record(time.perf_counter() - t0)
+            self.stats["ticks"] += 1
+            self.stats["live_slot_ticks"] += len(live)
+            emitted = []
+            for i in live:
+                s = self._slots[i]
+                s.pos += 1
+                s.last = int(tok[i])
+                s.out.append(s.last)
+                emitted.append((s.req.rid, s.last))
+                self._retire_if_done(i)
+            return emitted
 
     @property
     def occupancy(self) -> float:

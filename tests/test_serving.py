@@ -1,6 +1,9 @@
 """Serving engine: OOV-safe sampling, donated caches, continuous batching
 (slot lifecycle, bit-exact parity with single-request generate), merged-model
 checkpoint round-trip."""
+import contextlib
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -323,3 +326,83 @@ def test_merged_checkpoint_roundtrip_through_engine(tmp_path):
     out = eng.serve([req])
     ref = generate(model, merged, _batch_of(req), 6, max_len=32)[0]
     np.testing.assert_array_equal(out[0], ref)
+
+
+# ---------------------------------------------------------------------------
+# per-request records and host spans
+# ---------------------------------------------------------------------------
+
+
+def test_engine_request_records_follow_each_request():
+    """One record per admitted request, its times in order (arrival <=
+    admission start <= first token <= retire) and its token count that of
+    the output, which stays token-identical to ``generate``. A given
+    arrival is kept; reset() drops the records."""
+    cfg, model, params = _tiny()
+    max_len = 32
+    eng = ServingEngine(model, params, max_concurrency=2, max_len=max_len)
+    reqs = [Request(rid=i, tokens=_prompt(i, 8, cfg.vocab_size),
+                    max_new=3 + i) for i in range(4)]
+    late = Request(rid=9, tokens=_prompt(9, 8, cfg.vocab_size), max_new=2,
+                   arrival=time.perf_counter() - 5.0)
+    out = eng.serve(reqs + [late])
+    assert late.arrival is not None and reqs[0].arrival is None
+    snap = eng.snapshot()
+    recs = {r["rid"]: r for r in snap["requests"]}
+    assert sorted(recs) == [0, 1, 2, 3, 9]
+    for rid, r in recs.items():
+        assert r["arrival"] <= r["admit"] <= r["first"] <= r["retire"]
+        assert r["tokens"] == len(out[rid])
+    assert recs[9]["arrival"] == late.arrival
+    assert recs[9]["admit"] - recs[9]["arrival"] >= 5.0
+    assert snap["latency"]["queue_wait_s"]["count"] == 5
+    for r in reqs:
+        ref = generate(model, params, _batch_of(r), r.max_new,
+                       max_len=max_len)[0]
+        np.testing.assert_array_equal(out[r.rid], ref)
+    snap["requests"][0]["tokens"] = -1  # a copy: the engine's stays
+    assert eng.snapshot()["requests"][0]["tokens"] != -1
+    eng.reset()
+    assert eng.snapshot()["requests"] == []
+
+
+def test_engine_spans_cover_each_step_and_admission(monkeypatch):
+    """With the host-span hook patched to a recorder: every step() is
+    exactly one ``serve.step`` span holding one ``serve.fetch``, and every
+    admission exactly one ``serve.admit`` holding one
+    ``serve.first_token``; no span is open outside them."""
+    from repro.serving import engine as engine_mod
+    cfg, model, params = _tiny()
+    eng = ServingEngine(model, params, max_concurrency=2, max_len=32)
+    log = []
+
+    @contextlib.contextmanager
+    def recorder(name, **kw):
+        log.append(("enter", name))
+        yield
+        log.append(("exit", name))
+
+    monkeypatch.setattr(engine_mod, "annotate", recorder)
+    reqs = [Request(rid=i, tokens=_prompt(i, 8, cfg.vocab_size),
+                    max_new=2 + i) for i in range(3)]
+    eng.serve(reqs)
+    children = {"serve.step": ["serve.fetch"],
+                "serve.admit": ["serve.first_token"]}
+    stack, spans = [], []
+    for kind, name in log:
+        if kind == "enter":
+            assert (name in children) == (not stack), (name, stack)
+            if stack:
+                assert name in children[stack[0][0]]
+                stack[0][1].append(name)
+            stack.append((name, []))
+        else:
+            top, inner = stack.pop()
+            assert top == name
+            if not stack:
+                spans.append((name, inner))
+    assert not stack
+    for name, inner in spans:
+        assert inner == children[name]
+    assert sum(n == "serve.step" for n, _ in spans) == eng.stats["ticks"]
+    assert sum(n == "serve.admit" for n, _ in spans) == 3

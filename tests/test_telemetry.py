@@ -16,8 +16,10 @@ Key invariants pinned here:
 * the deterministic event stream is byte-reproducible, schema-validated
   at emit time, and resume-safe via truncate-to-seq.
 """
+import contextlib
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -202,6 +204,81 @@ def test_telemetry_never_perturbs_trajectory():
         assert np.array_equal(finals[0][k], finals[1][k]), k
     for k in scalars[0]:
         np.testing.assert_array_equal(scalars[0][k], scalars[1][k])
+
+
+def _tiny_lm_segment(scope_fn=None):
+    """(compiled segment, its arguments) of a tiny olmo (2 layers, 2
+    agents), traced with ``dsgd.scope`` replaced by ``scope_fn``."""
+    from repro.configs import get_config
+    from repro.models import build_model
+    cfg = get_config("olmo-1b").reduced(d_model=64, vocab=64, layers=2)
+    model = build_model(cfg)
+    opt = make_optimizer("adamw", 1e-2)
+    m, H, S, B, T = 2, 2, 2, 2, 8
+    state, spec = dsgd.init_panel_state(model.init_params, opt, m,
+                                        jax.random.PRNGKey(0))
+    seg = dsgd.make_panel_segment(model.loss_fn, opt, H, spec)
+    toks = jax.random.randint(jax.random.PRNGKey(3), (S, H, m, B, T + 1), 0,
+                              cfg.vocab_size)
+    batch = {"tokens": toks[..., :-1], "targets": toks[..., 1:],
+             "mask": jnp.ones((S, H, m, B, T), jnp.float32)}
+    Ws = jnp.full((S, m, m), 1.0 / m, jnp.float32)
+    args = (state, batch, Ws, jax.random.PRNGKey(7))
+    real = dsgd.scope
+    if scope_fn is not None:
+        dsgd.scope = scope_fn
+    try:
+        compiled = seg.lower(*args).compile()
+    finally:
+        dsgd.scope = real
+    return compiled, args
+
+
+def _scheduled_op_names(hlo_text):
+    """op_name of every instruction a profiler can show: reducer regions
+    (``to_apply=``) run inside their op and carry a bare name."""
+    reducers = set(re.findall(r"to_apply=%([\w.\-]+)", hlo_text))
+    names, comp = [], None
+    for line in hlo_text.splitlines():
+        if line and not line.startswith(" ") and line.rstrip().endswith("{"):
+            comp = line.split()[1 if line.startswith("ENTRY") else 0]
+            comp = comp.lstrip("%")
+        mt = re.search(r'op_name="([^"]*)"', line)
+        if mt and comp not in reducers:
+            names.append(mt.group(1))
+    return names
+
+
+def test_fwd_bwd_scope_splits_the_local_step():
+    """The model's forward and backward carry ``dsgd.fwd_bwd`` inside
+    ``dsgd.local_grad``; the panel's ops, and the row relayout, do not."""
+    compiled, _ = _tiny_lm_segment()
+    names = _scheduled_op_names(compiled.as_text())
+    fb = [n for n in names if "dsgd.fwd_bwd" in n]
+    assert fb and all(re.search(r"dsgd\.local_grad/.*dsgd\.fwd_bwd", n)
+                      for n in fb)
+    assert any("transpose" in n for n in fb)  # the backward pass too
+    assert not any("panel." in n for n in fb)
+    assert any("dsgd.local_grad" in n and "dsgd.fwd_bwd" not in n
+               for n in names)  # the relayout stays outside
+
+
+def test_fwd_bwd_scope_keeps_trajectory_bit_identical():
+    """A named scope is metadata: the segment's outputs are bit-identical
+    to the same segment traced without ``dsgd.fwd_bwd``."""
+    real = dsgd.scope
+
+    def without_fwd_bwd(name):
+        return (contextlib.nullcontext() if name == "dsgd.fwd_bwd"
+                else real(name))
+    outs = []
+    for fn in (None, without_fwd_bwd):
+        compiled, args = _tiny_lm_segment(fn)
+        outs.append(jax.device_get(compiled(*args)))
+    flat = [jax.tree_util.tree_leaves(o) for o in outs]
+    assert len(flat[0]) == len(flat[1])
+    for a, b in zip(*flat):
+        np.testing.assert_array_equal(a, b)
 
 
 def test_round_wire_bytes_unit():
