@@ -579,6 +579,27 @@ def unpanelize_state(state, spec):
             "step": state["step"]}
 
 
+def _agents_in_turn(one: Callable, pan, batch, rngs, spec):
+    """(grad panel, (m,) losses) of every agent's local step, the agents
+    one after another on the device that holds every row of ``pan``:
+    ``one(params, batch, rng) -> (grads, loss)`` runs on leaves sliced
+    out of agent-stacked slabs (panel.to_slabs) of the whole panel, and
+    only it sits under dsgd.fwd_bwd."""
+    def agent(xs):
+        slabs, b, r = xs
+        params = jax.tree_util.tree_unflatten(
+            spec.treedef,
+            [x.reshape(ls.shape) for x, ls in zip(slabs, spec.leaves)])
+        with scope("dsgd.fwd_bwd"):
+            g, l = one(params, b, r)
+        return [x.reshape(ls.slab)
+                for x, ls in zip(jax.tree.leaves(g), spec.leaves)], l
+
+    grads, losses = jax.lax.map(
+        agent, (panel_mod.to_slabs(pan, spec), batch, rngs))
+    return panel_mod.to_panel(grads, spec), losses
+
+
 def make_panel_segment(loss_fn: Callable, optimizer: Optimizer,
                        local_steps: int, spec, *, wire_dtype=None,
                        monitor: bool = True, telemetry: bool = False,
@@ -790,17 +811,10 @@ def make_panel_segment(loss_fn: Callable, optimizer: Optimizer,
         # the forward/backward, and the TPU compiler's code then grows
         # with D: olmo-1b at 1 layer, m=4 compiled to 735 MB of code in
         # 776 s with 30 GB of host memory, against 30 MB in 16 s this way.
-        # Only the model's forward and backward sit under dsgd.fwd_bwd; the
-        # row's relayout and lax.map's stacking stay outside it
-        def agent(xs):
-            row, b, r = xs
-            params = panel_mod.from_panel(row, spec)
-            with scope("dsgd.fwd_bwd"):
-                g, l = one(params, b, r)
-            gp = panel_mod.to_panel(jax.tree.map(lambda x: x[None], g), spec)
-            return {k: v[0] for k, v in gp.items()}, l
-
-        return jax.lax.map(agent, (pan, batch, rngs))
+        # The relayout is whole-panel and outside the loop: a panel tile
+        # holds 128 columns of every agent, so one agent's row touches a
+        # sliver of every tile, while agent-stacked slabs slice whole tiles
+        return _agents_in_turn(one, pan, batch, rngs, spec)
 
     def segment(state, batches, Ws, rng, active=None, global_rounds=None,
                 live=None):

@@ -2,6 +2,9 @@
 parity against the per-leaf tree-map reference path, Pallas panel_reduce
 kernel vs oracle, the donated scanned segment driver, and state
 panelize/unpanelize roundtrips."""
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -294,3 +297,120 @@ def test_panelize_unpanelize_roundtrip():
     for a, b in zip(jax.tree.leaves(pstate["panel"]),
                     jax.tree.leaves(ps["panel"])):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# ------------------------------------------------- one-device agent loop
+
+
+def _row_loop(one, pan, batch, rngs, spec):
+    """The agent loop of the row relayout, kept here as the oracle: each
+    agent's row is cut out of the (m, D) panel and rebuilt into leaves,
+    and its gradient is joined into a row that lax.map stacks."""
+    def agent(xs):
+        row, b, r = xs
+        params = panel_mod.from_panel(row, spec)
+        with dsgd.scope("dsgd.fwd_bwd"):
+            g, l = one(params, b, r)
+        gp = panel_mod.to_panel(jax.tree.map(lambda x: x[None], g), spec)
+        return {k: v[0] for k, v in gp.items()}, l
+
+    return jax.lax.map(agent, (pan, batch, rngs))
+
+
+def _keep_dtypes(opt):
+    """``opt`` with params and moments cast back to their stored dtypes,
+    so bf16 leaves stay bf16 through the segment's carry."""
+    def like(new, old):
+        return jax.tree.map(lambda a, b: a.astype(b.dtype), new, old)
+
+    def update(grads, state, params, step=None):
+        new_p, new_st = opt.update(grads, state, params, step)
+        return like(new_p, params), {
+            k: like(v, state[k]) if k in opt.moment_keys else v
+            for k, v in new_st.items()}
+
+    return dataclasses.replace(opt, update=update)
+
+
+def _mixed_lm_segment(monkeypatch, agent_loop=None, residency=None):
+    """(compiled segment, its arguments, spec) of a tiny gemma at m = 3
+    whose norm scales are stored in bf16: two dtype groups, and leaves of
+    96 and 48 scalars, no multiple of 128. ``agent_loop`` stands in for
+    ``dsgd._agents_in_turn`` while the segment is traced."""
+    from repro.configs import get_config
+    from repro.models import build_model
+    cfg = get_config("gemma-2b").reduced(d_model=48, vocab=64, layers=2)
+    model = build_model(cfg)
+
+    def init_params(rng):
+        p = model.init_params(rng)
+        blk = p["decoder"]["main"]["p0"]
+        for k in ("norm1", "norm2"):
+            blk[k]["scale"] = blk[k]["scale"].astype(jnp.bfloat16)
+        p["final_norm"]["scale"] = p["final_norm"]["scale"].astype(
+            jnp.bfloat16)
+        return p
+
+    opt = _keep_dtypes(make_optimizer("adamw", 1e-2))
+    m, H, S, B, T = 3, 2, 3, 2, 8
+    state, spec = dsgd.init_panel_state(init_params, opt, m,
+                                        jax.random.PRNGKey(0),
+                                        residency=residency)
+    toks = jax.random.randint(jax.random.PRNGKey(3), (S, H, m, B, T + 1), 0,
+                              cfg.vocab_size)
+    batch = {"tokens": toks[..., :-1], "targets": toks[..., 1:],
+             "mask": jnp.ones((S, H, m, B, T), jnp.float32)}
+    rng = np.random.default_rng(0)
+    Ws = jnp.asarray(np.stack([topology.random_matching(m, 0.5, rng),
+                               topology.identity(m),
+                               topology.fully_connected(m)]), jnp.float32)
+    args = (state, batch, Ws, jax.random.PRNGKey(7))
+    seg = dsgd.make_panel_segment(model.loss_fn, opt, H, spec)
+    with monkeypatch.context() as mp:
+        if agent_loop is not None:
+            mp.setattr(dsgd, "_agents_in_turn", agent_loop)
+        compiled = seg.lower(*args).compile()
+    return compiled, args, spec
+
+
+@pytest.mark.parametrize("residency", [None, "moments=bf16"])
+def test_whole_panel_relayout_matches_row_loop(monkeypatch, residency):
+    """The one-device agent loop on agent-stacked slabs of the whole panel
+    gives the row loop's final segment state and metrics, bit for bit."""
+    outs = []
+    for loop in (None, _row_loop):
+        compiled, args, spec = _mixed_lm_segment(monkeypatch, loop,
+                                                 residency)
+        outs.append(jax.device_get(compiled(*args)))
+    assert {k for k, _ in spec.groups} == {"float32", "bfloat16"}
+    assert any(ls.size % 128 for ls in spec.leaves)
+    flat = [jax.tree_util.tree_leaves_with_path(o) for o in outs]
+    assert len(flat[0]) == len(flat[1])
+    for (path, a), (_, b) in zip(*flat):
+        np.testing.assert_array_equal(a, b, err_msg=jax.tree_util.keystr(
+            path))
+
+
+_HLO_DTYPE = {"float32": "f32", "bfloat16": "bf16"}
+
+
+def _panel_slicing_ops(hlo_text, spec):
+    """(opcode, instruction) of every dynamic-slice and dynamic-update-slice
+    whose first operand is a whole (m, D_g) group panel."""
+    shape_of = dict(re.findall(r"%([\w.\-]+) = (\w+\[[\d,]*\])",
+                               hlo_text))
+    panels = {f"{_HLO_DTYPE[k]}[{spec.rows},{w}]" for k, w in spec.groups}
+    ops = re.findall(r"%([\w.\-]+) = \S+ (dynamic-(?:update-)?slice)"
+                     r"\(%([\w.\-]+)", hlo_text)
+    return [(op, name) for name, op, arg in ops
+            if shape_of.get(arg) in panels]
+
+
+def test_agent_loop_slices_no_whole_panel(monkeypatch):
+    """Compiled, the one-device agent loop reads no agent's row out of
+    the (m, D) panel and writes none into it: the row loop does both."""
+    compiled, _, spec = _mixed_lm_segment(monkeypatch)
+    assert _panel_slicing_ops(compiled.as_text(), spec) == []
+    compiled, _, _ = _mixed_lm_segment(monkeypatch, _row_loop)
+    kinds = {op for op, _ in _panel_slicing_ops(compiled.as_text(), spec)}
+    assert kinds == {"dynamic-slice", "dynamic-update-slice"}
