@@ -1,6 +1,6 @@
 """What every cell of the benchmark shares: finding a cell's files by the
-names in ``BENCHMARK.json``, the device check, the table of peaks, the
-compile cache, seeds, and the result line.
+names in ``BENCHMARK.json`` (its model family's module too), the device
+check, the table of peaks, the compile cache, seeds, and the result line.
 
 Nothing here imports the program (``src/repro``); the drivers in
 ``bench/train.py`` and ``bench/serve.py`` do.
@@ -65,14 +65,37 @@ def cell_metrics(bench, cell_name, kind):
             if cell_name in m.get("workloads", [cell_name])]
 
 
-def load_metric_reader(name, root=ROOT):
-    """The module ``bench/metrics/<name>.py`` of one per-layer metric."""
-    path = os.path.join(root, "bench", "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_"), path)
+def _load_module(path, name):
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_metric_reader(name, root=ROOT):
+    """The module ``bench/metrics/<name>.py`` of one per-layer metric."""
+    return _load_module(
+        os.path.join(root, "bench", "metrics", name + ".py"),
+        "bench_metric_" + name.replace(".", "_"))
+
+
+_FAMILIES = {}
+
+
+def family(cfg, root=ROOT):
+    """The module ``bench/models/<model_type>.py`` of a configuration
+    file's model family: everything that depends on the architecture
+    (the program's configuration and parameter tree, the reference's
+    forward pass, the counts)."""
+    name = cfg["model_type"]
+    path = os.path.join(root, "bench", "models", name + ".py")
+    if path not in _FAMILIES:
+        if not os.path.exists(path):
+            raise SystemExit(f"no family module {path} for model_type "
+                             f"{name!r}")
+        _FAMILIES[path] = _load_module(
+            path, "bench_family_" + name.replace(".", "_").replace("-", "_"))
+    return _FAMILIES[path]
 
 
 def read_metrics(per_layer, ctx):

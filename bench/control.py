@@ -54,9 +54,11 @@ def no_mix(jnp):
 TRAIN_FAULTS = ("half_batch", "no_mix")
 
 
-def train_readings(jax, cfg, traffic, seed, fault=None, ref32=None):
-    """(numbers, reference readings) of one seed: the program (with
-    ``fault`` planted, if any) against the float32 reference."""
+def train_readings(jax, cfg, traffic, seed, fault=None, ref32=None,
+                   chips=1):
+    """(numbers, reference readings, the reference's inputs) of one seed:
+    the program (with ``fault`` planted, if any) on ``chips`` chips
+    against the float32 reference placed as the program is."""
     from bench import train
     from repro.core import panel as panel_mod
     saved = panel_mod.mix_dense_mean
@@ -65,24 +67,28 @@ def train_readings(jax, cfg, traffic, seed, fault=None, ref32=None):
             panel_mod.mix_dense_mean = no_mix(jax.numpy)
         tr, prog = train.setup_and_check_calls(
             jax, cfg, traffic, seed,
-            loss_wrap=half_batch if fault == "half_batch" else None)
+            loss_wrap=half_batch if fault == "half_batch" else None,
+            chips=chips)
     finally:
         panel_mod.mix_dense_mean = saved
     K = traffic["check_calls"]
     inputs = (tr.k_w, tr.pool[:K], tr.Ws_host[:K])
+    mesh = tr.mesh
     tr.free()
     del tr
     gc.collect()
     if ref32 is None:
         ref32 = train.reference_run(jax, cfg, traffic, inputs, K,
-                                    jax.numpy.float32)
+                                    jax.numpy.float32, mesh=mesh)
     return train.compare(prog, ref32), ref32, inputs
 
 
-def train_control(jax, cfg, traffic, inputs, ref32):
+def train_control(jax, cfg, traffic, inputs, ref32, chips=1):
     from bench import train
-    ctl = train.reference_run(jax, cfg, traffic, inputs,
-                              traffic["check_calls"], jax.numpy.bfloat16)
+    ctl = train.reference_run(
+        jax, cfg, traffic, inputs, traffic["check_calls"],
+        jax.numpy.bfloat16,
+        mesh=train.placement(chips, cfg["job"]["agents"]))
     return train.compare(ctl, ref32)
 
 
@@ -107,19 +113,22 @@ def main(argv=None):
         print(json.dumps(row), flush=True)
 
     n = max(args.sound, args.control, args.faults)
+    chips = cell["chips"]
     for i in range(n):
         seed = args.seed + i
         if traffic["kind"] == "train":
-            nums, ref32, inputs = train_readings(jax, cfg, traffic, seed)
+            nums, ref32, inputs = train_readings(jax, cfg, traffic, seed,
+                                                 chips=chips)
             if i < args.sound:
                 out("sound", seed, nums)
             if i < args.control:
                 out("control", seed, train_control(jax, cfg, traffic,
-                                                   inputs, ref32))
+                                                   inputs, ref32, chips))
             if i < args.faults:
                 for f in TRAIN_FAULTS:
                     nums, _, _ = train_readings(jax, cfg, traffic, seed,
-                                                fault=f, ref32=ref32)
+                                                fault=f, ref32=ref32,
+                                                chips=chips)
                     out(f, seed, nums)
             del ref32, inputs
         else:

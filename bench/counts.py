@@ -2,45 +2,36 @@
 shapes alone: the yardstick of every share of a peak. A later kernel that
 does the same work reads against the same counts.
 
-The model's matrices are the attention projections, the SwiGLU MLP and
-the tied head over the padded vocabulary (the embedding lookup does no
-arithmetic). Causal attention needs, per layer and token at position i,
-2 * heads * head_dim * (i + 1) multiply-adds for the scores and as many
-for the values.
+What depends on the architecture is the model family's
+(``bench/models/<model_type>.py``): the parameters the program holds;
+the matmul parameters each token multiplies (the active ones: the
+experts a token is routed to, not every expert; the head, tied to the
+embedding or not, over the padded vocabulary; the embedding lookup does
+no arithmetic); the attention FLOPs of a token at a given context; and
+the cache bytes of one position. The rest is here: a matmul parameter
+costs 2 FLOPs a token forward, and backward twice that.
 """
 from __future__ import annotations
+
+from bench.common import family
 
 DTYPE_BYTES = {"float32": 4, "bfloat16": 2, "int8": 1}
 
 
-def head_dim(cfg):
-    return cfg["hidden_size"] // cfg["num_attention_heads"]
-
-
-def layer_matmul_params(cfg):
-    d, f = cfg["hidden_size"], cfg["intermediate_size"]
-    hd = head_dim(cfg)
-    q = cfg["num_attention_heads"] * hd
-    kv = cfg["num_key_value_heads"] * hd
-    return d * q + 2 * d * kv + q * d + 3 * d * f
-
-
 def matmul_params(cfg):
-    """Weights that multiply every token: the layers and the tied head."""
-    return (cfg["num_hidden_layers"] * layer_matmul_params(cfg)
-            + cfg["hidden_size"] * cfg["padded_vocab_size"])
+    """Weights that multiply every token, the head included."""
+    return family(cfg).matmul_params(cfg)
 
 
 def params(cfg):
-    """Every parameter the program holds (the tied table counted once)."""
-    return matmul_params(cfg)
+    """Every parameter the program holds (a tied table counted once)."""
+    return family(cfg).params(cfg)
 
 
 def attn_flops(cfg, positions):
     """Forward attention FLOPs of all layers for one token that attends to
     ``positions`` positions (itself included)."""
-    return (4 * cfg["num_attention_heads"] * head_dim(cfg) * positions
-            * cfg["num_hidden_layers"])
+    return family(cfg).attn_flops(cfg, positions)
 
 
 def train_flops_per_token(cfg, seq):
@@ -64,8 +55,8 @@ def decode_flops(cfg, context):
 
 
 def kv_bytes_per_position(cfg, dtype="float32"):
-    return (2 * cfg["num_hidden_layers"] * cfg["num_key_value_heads"]
-            * head_dim(cfg) * DTYPE_BYTES[dtype])
+    """Cache bytes of one position (keys and values, or a latent cache)."""
+    return family(cfg).cache_bytes_per_position(cfg, DTYPE_BYTES[dtype])
 
 
 def weight_bytes(cfg, dtype="float32"):
@@ -73,9 +64,9 @@ def weight_bytes(cfg, dtype="float32"):
 
 
 def decode_step_bytes(cfg, live_positions, dtype="float32"):
-    """HBM bytes one decode step needs: every weight once, and the keys and
-    values of the live positions of every slot (not the empty rest of a
-    dense cache)."""
+    """HBM bytes one decode step needs: every weight once, and the cache
+    of the live positions of every slot (not the empty rest of a dense
+    cache)."""
     return (weight_bytes(cfg, dtype)
             + kv_bytes_per_position(cfg, dtype) * live_positions)
 

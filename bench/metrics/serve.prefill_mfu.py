@@ -1,6 +1,7 @@
 """serve.prefill_mfu (%): the FLOPs of the window's prefills over the device
 time of the prefill programs, over the bf16 peak. Moves
-serve_ttft_p50_ms."""
+serve_itl_p95_ms: a prefill runs between two decode steps, so it
+lengthens the gap between two tokens of every live request."""
 from bench import counts
 
 

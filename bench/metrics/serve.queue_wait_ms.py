@@ -3,7 +3,7 @@ admitted in the window, of the time from a request's arrival to the start
 of its admission, read from the engine's per-request records
 (``ctx["engine"]``: ``ServingEngine.snapshot()`` taken right after the
 window). It holds the wait behind the step in flight and for a free
-slot. Moves serve_ttft_p50_ms."""
+slot. Moves serve_itl_p95_ms, the length of the step waited out."""
 import statistics
 
 

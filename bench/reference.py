@@ -1,77 +1,25 @@
-"""Plain reference of OLMo (arXiv:2402.00838) in ``jax.numpy``: the
-forward pass, the loss and its gradients, AdamW, the gossip mix
-``W @ Theta``, and full-sequence logits for serving.
+"""Plain reference in ``jax.numpy``: the loss and its gradients, AdamW,
+the gossip mix ``W @ Theta``, and full-sequence logits for serving.
 
-It imports nothing of the program. Weights come from :func:`make_params`
-(the benchmark makes them from the seed and hands the same arrays to the
-program), in one layout: ``embed`` (V_padded, d) and the per-layer
-matrices stacked over a leading layer axis.
+It imports nothing of the program. What depends on the architecture (the
+weights from the seed, the forward pass and the loss) is the family
+module's that the configuration file's ``model_type`` names
+(``bench/models/<model_type>.py``, found by ``bench.common.family``);
+this module holds what every family shares. Weights come from
+:func:`make_params` (the benchmark makes them from the seed and hands the
+same arrays to the program).
 
 Float32 runs under ``jax.default_matmul_precision("highest")``. Lower
 precisions give the controls the checks must reject: every weight and
 activation in bfloat16, or float8 e4m3 operands in every weight matmul
 and bfloat16 elsewhere.
-
-Departures from the published description, each because the program
-runs that way and the configuration file records it:
-
-- LayerNorm epsilon 1e-6 (OLMo: 1e-5); non-parametric, as published.
-- The tied head projects to the padded vocabulary (a multiple of 256,
-  50432 for OLMo's 50304) and the training softmax runs over every
-  padded column; serving masks the padding before the argmax.
-- Initial weights are normal with standard deviation 1/sqrt(fan_in)
-  (the embedding 1/sqrt(d_model)), not OLMo's initialisation.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-LAYER_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_in", "w_out")
-
-
-def padded_vocab(cfg):
-    return cfg["padded_vocab_size"]
-
-
-def shapes(cfg):
-    """{name: shape} of every weight in the benchmark's layout."""
-    d, f = cfg["hidden_size"], cfg["intermediate_size"]
-    h, kv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    hd = d // h
-    L = cfg["num_hidden_layers"]
-    return {"embed": (padded_vocab(cfg), d),
-            "wq": (L, d, h * hd), "wk": (L, d, kv * hd),
-            "wv": (L, d, kv * hd), "wo": (L, h * hd, d),
-            "w_gate": (L, d, f), "w_in": (L, d, f), "w_out": (L, f, d)}
-
-
-def make_params(cfg, key):
-    """Every weight from ``key`` (call inside one jit)."""
-    out = {}
-    for i, (name, shp) in enumerate(sorted(shapes(cfg).items())):
-        fan_in = shp[-1] if name == "embed" else shp[-2]
-        out[name] = (jax.random.normal(jax.random.fold_in(key, i), shp,
-                                       jnp.float32) / np.sqrt(fan_in))
-    return out
-
-
-def layer_norm(x, eps):
-    mu = jnp.mean(x, -1, keepdims=True)
-    var = jnp.mean(jnp.square(x - mu), -1, keepdims=True)
-    return (x - mu) / jnp.sqrt(var + eps)
-
-
-def rope(x, positions, theta):
-    """x: (B, S, H, hd); rotates the two halves of each head (GPT-NeoX
-    layout, as OLMo)."""
-    hd = x.shape[-1]
-    inv = 1.0 / (theta ** (np.arange(0, hd, 2, dtype=np.float32) / hd))
-    ang = positions[:, :, None, None].astype(jnp.float32) * inv
-    cos, sin = jnp.cos(ang).astype(x.dtype), jnp.sin(ang).astype(x.dtype)
-    x1, x2 = x[..., :hd // 2], x[..., hd // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+from bench import common
 
 
 def matmul(a, w):
@@ -85,62 +33,20 @@ def fp8_matmul(a, w):
                       preferred_element_type=jnp.float32).astype(a.dtype)
 
 
-def block(cfg, x, p, positions, mm=matmul):
-    """One decoder layer: pre-LN causal self-attention and SwiGLU MLP,
-    each added to the residual stream. ``mm`` multiplies activations by
-    weights."""
-    B, S, d = x.shape
-    h, kv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    hd = d // h
-    eps = cfg["layer_norm_eps"]
-    a = layer_norm(x, eps)
-    q = rope(mm(a, p["wq"]).reshape(B, S, h, hd), positions,
-             cfg["rope_theta"])
-    k = rope(mm(a, p["wk"]).reshape(B, S, kv, hd), positions,
-             cfg["rope_theta"])
-    v = mm(a, p["wv"]).reshape(B, S, kv, hd)
-    rep = h // kv
-    k = jnp.repeat(k, rep, axis=2)
-    v = jnp.repeat(v, rep, axis=2)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(hd).astype(x.dtype)
-    causal = np.tril(np.ones((S, S), bool))
-    s = jnp.where(causal, s, jnp.asarray(-1e30 if x.dtype == jnp.float32
-                                         else -3e38, x.dtype))
-    w = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhqk,bkhd->bqhd", w, v).reshape(B, S, h * hd)
-    x = x + mm(o, p["wo"])
-    a = layer_norm(x, eps)
-    return x + mm(jax.nn.silu(mm(a, p["w_gate"])) * mm(a, p["w_in"]),
-                  p["w_out"])
-
-
-def hidden(cfg, params, tokens, mm=matmul):
-    """Final-normed hidden states (B, S, d), layer after layer."""
-    dt = params["embed"].dtype
-    x = params["embed"][tokens]
-    B, S = tokens.shape
-    pos = jnp.broadcast_to(jnp.arange(S), (B, S))
-    layers = {k: params[k] for k in LAYER_KEYS}
-
-    def body(x, p):
-        return block(cfg, x, p, pos, mm).astype(dt), None
-
-    x, _ = jax.lax.scan(body, x, layers)
-    return layer_norm(x, cfg["layer_norm_eps"])
+def make_params(cfg, key):
+    """Every weight from ``key`` (call inside one jit)."""
+    return common.family(cfg).make_params(cfg, key)
 
 
 def logits(cfg, params, tokens, mm=matmul):
-    """(B, S, V_padded) logits of the tied head."""
-    return mm(hidden(cfg, params, tokens, mm), params["embed"].T)
+    """(B, S, V_padded) logits; ``mm`` multiplies activations by
+    weights."""
+    return common.family(cfg).logits(cfg, params, tokens, mm)
 
 
-def loss(cfg, params, tokens, targets):
-    """Mean next-token cross-entropy over every position, softmax over the
-    padded vocabulary."""
-    lg = logits(cfg, params, tokens)
-    lse = jax.nn.logsumexp(lg, axis=-1)
-    tgt = jnp.take_along_axis(lg, targets[..., None], axis=-1)[..., 0]
-    return jnp.mean(lse - tgt)
+def loss(cfg, params, tokens, targets, mm=matmul):
+    """Mean next-token cross-entropy over every position."""
+    return common.family(cfg).loss(cfg, params, tokens, targets, mm)
 
 
 def cast(params, dtype):
@@ -150,14 +56,32 @@ def cast(params, dtype):
 # ------------------------------------------------------------- training
 
 
-def agent_grads(cfg, dtype):
+def agent_grads(cfg, dtype, rows=None):
     """(stacked params, stacked batches) -> (losses (m,), stacked grads
-    in float32), agent after agent."""
+    in float32), agent after agent, an agent's batch ``rows`` sequences at
+    a time (so that it fits; the whole batch where ``rows`` is None or not
+    under it), the loss and gradients the mean over those equal blocks.
+    A single block is added to zero and divided by one, both exact."""
+    def grad(p, tok, tgt):
+        return jax.value_and_grad(
+            lambda q: loss(cfg, cast(q, dtype), tok, tgt))(p)
+
     def one(xs):
         p, tok, tgt = xs
-        lval, g = jax.value_and_grad(
-            lambda q: loss(cfg, cast(q, dtype), tok, tgt))(p)
-        return lval.astype(jnp.float32), g
+        B = tok.shape[0]
+        r = B if rows is None else min(rows, B)
+        if B % r:
+            raise ValueError(f"a batch of {B} in blocks of {r}")
+        n = B // r
+
+        def block(acc, xs):
+            lval, g = grad(p, *xs)
+            return jax.tree.map(jnp.add, acc, (lval, g)), None
+        zero = (jnp.zeros((), jnp.float32), jax.tree.map(jnp.zeros_like, p))
+        (lval, g), _ = jax.lax.scan(
+            block, zero, (tok.reshape((n, r) + tok.shape[1:]),
+                          tgt.reshape((n, r) + tgt.shape[1:])))
+        return lval / n, jax.tree.map(lambda x: x / n, g)
 
     def run(params, tokens, targets):
         return jax.lax.map(one, (params, tokens, targets))

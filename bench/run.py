@@ -4,16 +4,17 @@
         --trace <0|1>
 
 The cell, its configuration file and its traffic mix are found by the
-names in ``BENCHMARK.json``; its limits for ``correct`` in
+names in ``BENCHMARK.json``; the configuration's model family in
+``bench/models/<model_type>.py``; its limits for ``correct`` in
 ``bench/limits/<workload>.json``. The run stops with a non-zero exit
 code, and prints no result, unless JAX finds TPUs, as many as the cell
 asks for. Set-up (making the weights and the traffic from the seed,
 loading or compiling every program the window runs) is timed from the
 start of the process as ``setup_s``; then the window runs for
 ``--seconds``; then the outputs of the timed path are compared with the
-plain reference in ``bench/reference.py``. With ``--trace 1`` the
-window runs under the profiler and the line carries the per-layer
-metrics instead of the end-to-end ones.
+plain reference (``bench/reference.py`` and the family's forward pass).
+With ``--trace 1`` the window runs under the profiler and the line
+carries the per-layer metrics instead of the end-to-end ones.
 
 The last line of stdout is one JSON object: ``correct``, ``attempted``,
 ``failed``, ``metrics``, ``device`` (and with ``--trace 1`` the device's

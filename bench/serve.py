@@ -43,11 +43,11 @@ class Server:
         self.k_w = common.seed_key(jax, seed, 1)
         self.make_params = jax.jit(lambda k: ref.make_params(cfg, k))
         params = self.make_params(self.k_w)
-        program.check_layout(jax, self.model, params)
+        program.check_layout(jax, cfg, self.model, params)
         self.params = params
         sv = cfg["serving"]
         self.engine = ServingEngine(
-            self.model, program.to_program(params),
+            self.model, program.to_program(cfg, params),
             max_concurrency=sv["slots"], max_len=sv["max_len"],
             temperature=0.0, rng=common.seed_key(jax, seed, 2))
         (self.due, self.plen, self.nout), rng = gen.open_loop(
@@ -84,7 +84,8 @@ class Server:
                 break
             while i < N and self.due[i] <= now:
                 e.submit(Request(rid=i, tokens=self.prompts[i],
-                                 max_new=int(self.nout[i])))
+                                 max_new=int(self.nout[i]),
+                                 arrival=t0 + self.due[i]))
                 late.append(now - self.due[i])
                 i += 1
             if e.queue and e.free_slots():
@@ -267,6 +268,8 @@ def run(jax, cell, cfg, traffic, limits, *, seed, seconds, trace, t_start,
         w = sv.window(seconds)
     if trace:
         jax.profiler.stop_trace()
+    # the engine's per-request records, before finish() frees it
+    engine = sv.engine.snapshot()
     device = common.device_info(devs)
     ttft, itl, tokens = latency_metrics(w, sv.due, seconds)
     nums, _ = checked(sv, *sv.finish(seed))
@@ -295,8 +298,6 @@ def run(jax, cell, cfg, traffic, limits, *, seed, seconds, trace, t_start,
               "failed": 0, "device": device}
     if not trace:
         result["metrics"] = {
-            "serve_ttft_p50_ms": {"value": 1e3 * common.quantile(ttft, 0.50),
-                                  "unit": "ms"},
             "serve_itl_p95_ms": {"value": 1e3 * common.quantile(itl, 0.95),
                                  "unit": "ms"},
             "serve_tokens_per_s": {"value": tokens / seconds,
@@ -312,8 +313,8 @@ def run(jax, cell, cfg, traffic, limits, *, seed, seconds, trace, t_start,
     result["breakdown"] = {"device_ops": red["device_ops"],
                            "idle_gaps": red["idle_gaps"]}
     ctx = {"cfg": cfg, "traffic": traffic, "peaks": peaks,
-           "chips": len(devs), "reduced": red,
-           "counts": window_counts(w, sv)}
+           "chips": len(devs), "reduced": red, "extract": ex,
+           "engine": engine, "ttft": ttft, "counts": window_counts(w, sv)}
     result["metrics"] = common.read_metrics(per_layer, ctx)
     common.clear_dir(prof)
     return result, table

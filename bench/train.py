@@ -1,7 +1,13 @@
-"""Training cells: decentralized training of m agents on one chip, through
-the segment function that ``repro.core.dsgd.make_panel_segment`` builds,
-fed as ``repro.launch.train`` feeds it (``init_panel_state``,
-``make_schedule``, stacked mixing matrices and global-round flags).
+"""Training cells: decentralized training of m agents, through the segment
+function that ``repro.core.dsgd.make_panel_segment`` builds, fed as
+``repro.launch.train`` feeds it (``init_panel_state``, ``make_schedule``,
+stacked mixing matrices and global-round flags).
+
+Placement follows from the cell (:func:`placement`): on one chip every
+agent runs on one device; with as many chips as agents, one agent per
+chip, the panel's rows over the host mesh (``repro.launch.mesh.
+make_host_mesh``), as ``repro.launch.train --mesh host`` runs it. The
+reference follows the same placement.
 
 Set-up makes the weights and a pool of distinct token segments on the
 device from the seed, builds the state, and drives the first
@@ -22,6 +28,31 @@ from bench import reference as ref
 
 
 MOMENT_DTYPES = {"bf16": "bfloat16", "f32": "float32"}
+# the reference's forward and backward take an agent's batch this many
+# sequences at a time: the one-chip cells' batch of 4 whole; a batch of 16 x
+# 512 in four blocks keeps its float32 activations within a chip
+REFERENCE_ROWS = 4
+
+
+def placement(chips, agents):
+    """None when every agent runs on one chip; the host mesh, one agent per
+    chip, when there are as many chips as agents. Any other pair is an
+    error."""
+    if chips == 1:
+        return None
+    if chips == agents:
+        from repro.launch.mesh import make_host_mesh
+        return make_host_mesh(chips)
+    raise ValueError(f"{agents} agents on {chips} chips: a training cell "
+                     f"runs on one chip or on one chip per agent")
+
+
+def agent_rows(mesh, lead=0):
+    """The sharding of an array whose axis ``lead`` is the agent axis: one
+    agent per chip of ``mesh``."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+    return NamedSharding(mesh, P(*([None] * lead), ("pod", "agent")))
 
 
 def round_fault(W, is_global, t, every):
@@ -75,7 +106,8 @@ def job_segments(traffic, m, seed, n_jobs):
 class Trainer:
     """The program's training path for one cell, at the cell's sizes."""
 
-    def __init__(self, jax, cfg, traffic, seed, *, loss_wrap=None):
+    def __init__(self, jax, cfg, traffic, seed, *, loss_wrap=None,
+                 chips=1):
         import jax.numpy as jnp
         from repro.core import dsgd
         from repro.models import build_model
@@ -86,18 +118,37 @@ class Trainer:
         self.m, self.b, self.seq = job["agents"], job["batch"], job["seq_len"]
         self.S = traffic["rounds_per_segment"]
         self.H = traffic["local_steps"]
+        self.mesh = placement(chips, self.m)
         self.model = build_model(program.model_config(cfg))
         self.opt = make_optimizer("adamw", job["lr"],
                                   weight_decay=job["weight_decay"])
         self.make_params = jax.jit(lambda k: ref.make_params(cfg, k))
         self.k_w = common.seed_key(jax, seed, 1)
         params = self.make_params(self.k_w)
-        program.check_layout(jax, self.model, params)
-        tree = program.to_program(params)
+        program.check_layout(jax, cfg, self.model, params)
+        tree = program.to_program(cfg, params)
         del params
-        self.state, self.spec = dsgd.init_panel_state(
-            lambda _: tree, self.opt, self.m, common.seed_key(jax, seed, 2),
-            same_init=True, residency=f"moments={job['moments']}")
+        key = common.seed_key(jax, seed, 2)
+
+        def init(t, k):
+            return dsgd.init_panel_state(
+                lambda _: t, self.opt, self.m, k, same_init=True,
+                residency=f"moments={job['moments']}", mesh=self.mesh)
+        if self.mesh is None:
+            self.state, self.spec = init(tree, key)
+        else:
+            # built in one program, each chip making its own agent's rows:
+            # run eagerly, the stacked panel and moments of every agent
+            # would first lie whole on the first chip
+            box = {}
+
+            def build(t, k):
+                state, box["spec"] = init(t, k)
+                return state
+            shapes = jax.eval_shape(build, tree, key)
+            self.spec = box["spec"]
+            self.state = jax.jit(build, out_shardings=(
+                dsgd.panel_state_shardings(shapes, self.spec)))(tree, key)
         del tree
         loss_fn = self.model.loss_fn
         if loss_wrap is not None:
@@ -115,6 +166,11 @@ class Trainer:
         self.pool = [{"tokens": pool["tokens"][i],
                       "targets": pool["targets"][i], "mask": ones[i]}
                      for i in range(P)]
+        if self.mesh is not None:
+            # (S, H, m, b, seq): each agent's batches on its own chip
+            rows = agent_rows(self.mesh, lead=2)
+            self.pool = [{k: jax.device_put(v, rows) for k, v in b.items()}
+                         for b in self.pool]
         del pool, ones
         n_jobs = traffic.get("jobs", 4)
         segs, self.schedule_faults = job_segments(traffic, self.m, seed,
@@ -126,20 +182,39 @@ class Trainer:
         self.keys = [jax.random.fold_in(k_seg, i) for i in range(len(segs))]
         self.active = jnp.ones((self.S,), bool)
         self.calls = 0
+        self.layout = None
+        if self.mesh is not None:
+            # one program, compiled (or loaded from the cache) once, for the
+            # state as built. It returns each agent's step counter on its
+            # own chip, where the build replicates them: each call puts its
+            # state back in the layout the program takes
+            self.segment = self.segment.lower(*self.args(0)).compile()
+            self.layout = self.segment.input_shardings[0][0]
 
     @property
     def tokens_per_call(self):
         return self.S * self.H * self.m * self.b * self.seq
 
+    def args(self, i):
+        """The segment's arguments for call ``i``."""
+        w = i % len(self.Ws)
+        return (self.state, self.pool[i % len(self.pool)], self.Ws[w],
+                self.keys[w], self.active, self.glob[w], None)
+
     def call(self):
         """Dispatch the next segment; returns its metrics (not waited on)."""
-        i = self.calls
-        w = i % len(self.Ws)
-        self.state, mets = self.segment(
-            self.state, self.pool[i % len(self.pool)], self.Ws[w],
-            self.keys[w], self.active, self.glob[w], None)
+        self.state, mets = self.segment(*self.args(self.calls))
+        if self.layout is not None:
+            self.state = self.jax.device_put(self.state, self.layout)
         self.calls += 1
         return mets
+
+    def compiled_text(self):
+        """The HLO text of the program the window runs."""
+        seg = self.segment
+        if not hasattr(seg, "as_text"):
+            seg = seg.lower(*self.args(0)).compile()
+        return seg.as_text()
 
     def deltas(self):
         """(m, leaves) norms of each agent's change of each weight since the
@@ -150,7 +225,7 @@ class Trainer:
 
         def run(pan, p0):
             tree = panel_mod.from_panel(pan, spec)
-            p = program.from_program(tree)
+            p = program.from_program(self.cfg, tree)
             return jnp.stack([
                 jnp.sqrt(jnp.sum(jnp.square(p[k] - p0[k][None]),
                                  axis=tuple(range(1, p[k].ndim))))
@@ -165,11 +240,14 @@ class Trainer:
         gc.collect()
 
 
-def reference_run(jax, cfg, traffic, trainer_inputs, calls, dtype):
+def reference_run(jax, cfg, traffic, trainer_inputs, calls, dtype,
+                  mesh=None):
     """The reference over the first ``calls`` segments: per round its mean
     loss, the norm of the agent-mean gradient (mean over the local
     steps) and Xi after the mix; per agent and weight the norm of the
-    change; per weight the norm of the first gradient."""
+    change; per weight the norm of the first gradient. With ``mesh`` the
+    agent-stacked parameters, moments and gradients lie one agent per
+    chip, and each chip runs the agent loop over its own agents."""
     jnp = jax.numpy
     job = cfg["job"]
     m = job["agents"]
@@ -179,11 +257,20 @@ def reference_run(jax, cfg, traffic, trainer_inputs, calls, dtype):
               wd=job["weight_decay"], moment_dtype=mom)
     with jax.default_matmul_precision("highest"):
         make = jax.jit(lambda k: ref.make_params(cfg, k))
-        grads_fn = jax.jit(ref.agent_grads(cfg, dtype))
         adam = jax.jit(lambda p, g, m_, v_, c: ref.adamw(p, g, m_, v_, c,
                                                          **hp),
                        donate_argnums=(0, 2, 3))
-        mix = jax.jit(ref.mix, donate_argnums=(1,))
+        if mesh is None:
+            grads_fn = jax.jit(ref.agent_grads(cfg, dtype, REFERENCE_ROWS))
+            mix = jax.jit(ref.mix, donate_argnums=(1,))
+        else:
+            from jax.sharding import PartitionSpec as P
+            rows = agent_rows(mesh)
+            grads_fn = jax.jit(jax.shard_map(
+                ref.agent_grads(cfg, dtype, REFERENCE_ROWS), mesh=mesh,
+                in_specs=P(("pod", "agent")), out_specs=P(("pod", "agent")),
+                check_vma=False))
+            mix = jax.jit(ref.mix, donate_argnums=(1,), out_shardings=rows)
         xi = jax.jit(ref.consensus)
         gnorm = jax.jit(lambda g: ref.tree_norm(
             jax.tree.map(lambda x: jnp.mean(x, 0), g)))
@@ -192,10 +279,18 @@ def reference_run(jax, cfg, traffic, trainer_inputs, calls, dtype):
                                        axis=tuple(range(1, g[k].ndim)))))
              for k in sorted(g)]))
         p0 = make(k_w)
-        theta = jax.tree.map(
-            lambda x: jnp.broadcast_to(x[None], (m,) + x.shape), p0)
-        mm = jax.tree.map(lambda x: jnp.zeros(x.shape, mom), theta)
-        vv = jax.tree.map(lambda x: jnp.zeros(x.shape, mom), theta)
+        if mesh is None:
+            theta = jax.tree.map(
+                lambda x: jnp.broadcast_to(x[None], (m,) + x.shape), p0)
+            mm = jax.tree.map(lambda x: jnp.zeros(x.shape, mom), theta)
+            vv = jax.tree.map(lambda x: jnp.zeros(x.shape, mom), theta)
+        else:
+            theta = jax.jit(lambda p: jax.tree.map(
+                lambda x: jnp.broadcast_to(x[None], (m,) + x.shape), p),
+                out_shardings=rows)(p0)
+            zeros = jax.jit(lambda t: jax.tree.map(
+                lambda x: jnp.zeros(x.shape, mom), t), out_shardings=rows)
+            mm, vv = zeros(theta), zeros(theta)
         count = jnp.zeros((), jnp.int32)
         loss_r, gn_r, xi_r, first_g = [], [], [], None
         for c in range(calls):
@@ -262,10 +357,11 @@ def compare(prog, refr):
             "schedule_faults": prog.get("schedule_faults", 0)}
 
 
-def setup_and_check_calls(jax, cfg, traffic, seed, loss_wrap=None):
+def setup_and_check_calls(jax, cfg, traffic, seed, loss_wrap=None,
+                          chips=1):
     """Build the trainer and drive the check calls. Returns (trainer,
     program readings of those calls)."""
-    tr = Trainer(jax, cfg, traffic, seed, loss_wrap=loss_wrap)
+    tr = Trainer(jax, cfg, traffic, seed, loss_wrap=loss_wrap, chips=chips)
     K = traffic["check_calls"]
     mets = [jax.device_get(tr.call()) for _ in range(K)]
     prog = {k: np.concatenate([np.asarray(x[k]) for x in mets])
@@ -277,7 +373,9 @@ def setup_and_check_calls(jax, cfg, traffic, seed, loss_wrap=None):
 
 def run(jax, cell, cfg, traffic, limits, *, seed, seconds, trace, t_start,
         devs, peaks, per_layer):
-    tr, prog = setup_and_check_calls(jax, cfg, traffic, seed)
+    tr, prog = setup_and_check_calls(jax, cfg, traffic, seed,
+                                     chips=cell["chips"])
+    mesh = tr.mesh
     inputs = (tr.k_w, tr.pool[:traffic["check_calls"]],
               tr.Ws_host[:traffic["check_calls"]])
     setup_s = time.perf_counter() - t_start
@@ -285,9 +383,7 @@ def run(jax, cell, cfg, traffic, limits, *, seed, seconds, trace, t_start,
     prof = None
     if trace:
         from bench import trace as tr_mod
-        names = tr_mod.op_names(tr.segment.lower(
-            tr.state, tr.pool[0], tr.Ws[0], tr.keys[0], tr.active,
-            tr.glob[0], None).compile().as_text())
+        names = tr_mod.op_names(tr.compiled_text())
         prof = common.work_dir("trace", cell["name"])
         jax.profiler.start_trace(prof)
     watch = common.WindowWatch(jax)
@@ -326,7 +422,7 @@ def run(jax, cell, cfg, traffic, limits, *, seed, seconds, trace, t_start,
     gc.collect()
 
     refr = reference_run(jax, cfg, traffic, inputs, traffic["check_calls"],
-                         jax.numpy.float32)
+                         jax.numpy.float32, mesh=mesh)
     nums = compare(prog, refr)
     nums["nonfinite_losses"] = int(np.sum(~np.isfinite(losses)))
     checks = [(k, nums[k], limits[k]) for k in limits]
@@ -356,5 +452,6 @@ def run(jax, cell, cfg, traffic, limits, *, seed, seconds, trace, t_start,
 
 
 SCOPES = {"local_grad": ["dsgd.local_grad"],
+          "fwd_bwd": ["dsgd.fwd_bwd"],
           "local_update": ["dsgd.local_update"],
           "mix": ["panel.", "merge.panel"]}

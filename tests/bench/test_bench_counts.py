@@ -1,11 +1,11 @@
 """The benchmark's FLOP and byte counts against hand-worked values for
-olmo-1b at 1 and at 16 layers."""
+olmo-1b at 1, 2 and 16 layers."""
 import json
 import os
 
 import pytest
 
-from bench import counts
+from bench import common, counts
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -29,8 +29,15 @@ HEAD = 2048 * 50432
 def test_params(name, layers, params):
     cfg = _cfg(name)
     assert cfg["num_hidden_layers"] == layers
-    assert counts.layer_matmul_params(cfg) == LAYER == 67_108_864
+    olmo = common.family(cfg)
+    assert olmo.layer_matmul_params(cfg) == LAYER == 67_108_864
     assert counts.params(cfg) == layers * LAYER + HEAD == params
+
+
+def test_params_two_layers():
+    """Two layers, as an agent with a chip of its own holds them."""
+    cfg = dict(_cfg("olmo-1b-dsgd-m4"), num_hidden_layers=2)
+    assert counts.params(cfg) == 2 * LAYER + HEAD == 237_502_464
 
 
 def test_train_flops_per_token_one_layer():

@@ -23,7 +23,8 @@ def test_sound_run_is_correct(tiny_root, cell):
     assert res["correct"] is True
     m = res["metrics"]
     assert m["serve_tokens_per_s"]["value"] > 0
-    assert m["serve_ttft_p50_ms"]["value"] > 0
+    assert m["serve_itl_p95_ms"]["value"] > 0
+    assert "serve_ttft_p50_ms" not in m
     assert res["attempted"] > 10
 
 

@@ -1,8 +1,8 @@
-"""Training cells at test widths on the CPU: a sound run is correct; the
-control (the reference in bfloat16 in the program's place) reads above
-every limit-worthy sound reading; and a run with the timed path broken
-underneath comes out not correct, once for each fault a training cell on
-one chip can have."""
+"""One-chip training cells at test widths on the CPU: a sound run is
+correct; the control (the reference in bfloat16 in the program's place)
+reads above every limit-worthy sound reading; and a run with the timed
+path broken underneath comes out not correct, once for each fault a
+training cell on one chip can have."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,8 +10,9 @@ import pytest
 
 from bench import common, control, run, train
 
+# the one-chip cells (the four-chip cell's are in test_bench_x4.py)
 CELLS = [w["name"] for w in common.load_benchmark()["workloads"]
-         if w["traffic"].startswith("dsgd")]
+         if w["traffic"].startswith("dsgd") and w["chips"] == 1]
 
 
 def _run(root, cell):

@@ -79,14 +79,22 @@ def test_config_file(entry):
     for k in entry["reduced"]:
         assert not WIDTHS.search(k), k
     assert cfg["deployment"] and cfg["assumed"]
-    for k in ("hidden_size", "intermediate_size", "num_attention_heads",
-              "num_key_value_heads", "num_hidden_layers", "vocab_size",
-              "padded_vocab_size", "layer_norm_eps", "rope_theta"):
+    # the keys its family reads, and the family's program configuration
+    fam = common.family(cfg, ROOT)
+    for k in fam.CONFIG_KEYS:
         assert k in cfg
-    # published widths
-    assert (cfg["hidden_size"], cfg["intermediate_size"],
-            cfg["num_attention_heads"], cfg["vocab_size"]) == \
-        (2048, 8192, 16, 50304)
+    assert fam.program_config(cfg).num_layers == cfg["num_hidden_layers"]
+    # published widths: the family states its sources' sizes (a file
+    # whose source it does not state fails here); a key the file cuts is
+    # in ``reduced``, and its ``published`` block keeps the value
+    published = fam.PUBLISHED[cfg["source"]]
+    assert set(entry["reduced"]) <= set(published)
+    for k, v in cfg.items():  # every width the file gives
+        if WIDTHS.search(k) and isinstance(v, (int, float)):
+            assert k in published, k
+    for k, v in published.items():
+        held = cfg["published"][k] if k in entry["reduced"] else cfg[k]
+        assert held == v, k
 
 
 def test_unseen_config_and_traffic_are_picked_up(tmp_path):

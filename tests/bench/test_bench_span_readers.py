@@ -75,6 +75,14 @@ def test_engine_record_readers_without_records(ctx):
     assert _read("serve.first_token_ms", ctx) is None
 
 
+def test_ttft_reader_is_the_median_of_every_request():
+    # seconds from due time to first token, one request at the window's end
+    ctx = {"ttft": [0.040, 0.020, 0.060, 0.050, 3.0]}
+    assert _read("serve.ttft_p50_ms", ctx) == pytest.approx(50.0)
+    assert _read("serve.ttft_p50_ms", {"ttft": []}) is None
+    assert _read("serve.ttft_p50_ms", {}) is None
+
+
 # two devices over a 10 ms window. The engine's spans: a step 1..5 ms
 # (device busy 2..4) and an admission 6..8 ms (device 0 busy 6..7,
 # device 1 idle). The Python tracer's event of the caller's loop covers
