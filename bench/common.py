@@ -98,6 +98,27 @@ def family(cfg, root=ROOT):
     return _FAMILIES[path]
 
 
+def declarations(per_layer, attr, root=ROOT):
+    """{label: [key, ...]} that the readers of ``per_layer`` declare under
+    ``attr``: ``SCOPES`` (a label for the device time of ops whose
+    ``jax.named_scope`` path holds one of the keys) or ``MODULES`` (a
+    label for the device time inside runs of the compiled programs
+    named). A reader may share a label with another only with the same
+    keys; two that differ stop the run, naming both."""
+    out, owner = {}, {}
+    for m in per_layer:
+        mod = load_metric_reader(m["name"], root)
+        for label, keys in getattr(mod, attr, {}).items():
+            keys = list(keys)
+            if label in out and out[label] != keys:
+                raise SystemExit(
+                    f"bench: the readers {owner[label]} and {m['name']} "
+                    f"declare the {attr} label {label!r} with different "
+                    f"keys ({out[label]} and {keys}); nothing was run")
+            out[label], owner[label] = keys, owner.get(label, m["name"])
+    return out
+
+
 def read_metrics(per_layer, ctx):
     """{name: {value, unit}} of the per-layer metrics whose reader finds
     something to read in ``ctx``; a reader that finds nothing returns
@@ -136,12 +157,25 @@ def require_chips(jax, chips):
     return devs[:chips]
 
 
+def memory_peaks(devs, key="peak_bytes_in_use"):
+    """Each device's ``key`` of its memory stats so far (0 where the
+    backend reports none)."""
+    return [int((d.memory_stats() or {}).get(key, 0)) for d in devs]
+
+
+def print_memory_peaks(devs, when):
+    """Each chip's peak of buffers in use, and of memory reserved beside
+    them. Which of the two holds a compiled program's temporaries is the
+    runtime's affair; both are printed so that a run shows it."""
+    peaks = memory_peaks(devs)
+    print(f"memory peak per chip {when}: {peaks} B (fullest {max(peaks)} "
+          f"B); reserved {memory_peaks(devs, 'peak_bytes_reserved')} B",
+          flush=True)
+
+
 def device_info(devs):
-    peak = 0
-    for d in devs:
-        peak = max(peak, (d.memory_stats() or {}).get("peak_bytes_in_use", 0))
     return {"platform": devs[0].platform, "kind": devs[0].device_kind,
-            "count": len(devs), "memory_peak_bytes": int(peak)}
+            "count": len(devs), "memory_peak_bytes": max(memory_peaks(devs))}
 
 
 def use_compile_cache(jax):

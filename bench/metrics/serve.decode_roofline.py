@@ -5,6 +5,8 @@ cache counts as waste) over the HBM peak, over the device time of the
 decode-step programs (decode and sample). Moves serve_itl_p95_ms."""
 from bench import counts
 
+MODULES = {"decode": ["jit_step_fn"]}
+
 
 def read(ctx):
     cfg = ctx["cfg"]

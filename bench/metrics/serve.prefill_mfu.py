@@ -4,6 +4,8 @@ serve_itl_p95_ms: a prefill runs between two decode steps, so it
 lengthens the gap between two tokens of every live request."""
 from bench import counts
 
+MODULES = {"prefill": ["jit_prefill"]}
+
 
 def read(ctx):
     cfg = ctx["cfg"]

@@ -4,6 +4,8 @@ relayout around it) per local step, on the busiest chip. Reads the
 trace reduction's scope label ``fwd_bwd``; a program without the scope
 reads nothing. Moves train_tokens_per_s."""
 
+SCOPES = {"fwd_bwd": ["dsgd.fwd_bwd"]}
+
 
 def read(ctx):
     steps = ctx["counts"]["local_steps"]

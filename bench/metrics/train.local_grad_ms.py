@@ -2,6 +2,8 @@
 scope (every agent's forward and backward) per local step, on the
 busiest chip. Moves train_tokens_per_s."""
 
+SCOPES = {"local_grad": ["dsgd.local_grad"]}
+
 
 def read(ctx):
     steps = ctx["counts"]["local_steps"]

@@ -2,6 +2,8 @@
 folded mean, global merge, consensus) and ``merge.panel``, per round, on
 the busiest chip. Moves train_tokens_per_s."""
 
+SCOPES = {"mix": ["panel.", "merge.panel"]}
+
 
 def read(ctx):
     rounds = ctx["counts"]["rounds"]

@@ -5,6 +5,8 @@ stacking the gradient rows. With ``train.fwd_bwd_ms`` it adds up to
 ``train.local_grad_ms``; a program without the ``dsgd.fwd_bwd`` scope
 reads nothing. Moves train_tokens_per_s."""
 
+SCOPES = {"local_grad": ["dsgd.local_grad"], "fwd_bwd": ["dsgd.fwd_bwd"]}
+
 
 def read(ctx):
     steps = ctx["counts"]["local_steps"]

@@ -5,6 +5,8 @@ HBM peak, over the device time under ``dsgd.local_update`` per local
 step, on the busiest chip. Moves train_tokens_per_s."""
 from bench import counts
 
+SCOPES = {"local_update": ["dsgd.local_update"]}
+
 
 def read(ctx):
     cfg = ctx["cfg"]

@@ -14,7 +14,12 @@ start of the process as ``setup_s``; then the window runs for
 ``--seconds``; then the outputs of the timed path are compared with the
 plain reference (``bench/reference.py`` and the family's forward pass).
 With ``--trace 1`` the window runs under the profiler and the line
-carries the per-layer metrics instead of the end-to-end ones.
+carries the per-layer metrics instead of the end-to-end ones, each read
+by ``bench/metrics/<name>.py``. A reader may declare the labels it reads
+from the trace: ``SCOPES`` ({label: [named-scope substring, ...]}) and
+``MODULES`` ({label: [compiled program name, ...]}); the trace is reduced
+by the labels of every reader the cell reports, and two readers that give
+one label different keys stop the run before JAX starts.
 
 The last line of stdout is one JSON object: ``correct``, ``attempted``,
 ``failed``, ``metrics``, ``device`` (and with ``--trace 1`` the device's
@@ -49,15 +54,18 @@ def main(argv=None, *, t_start=T_START, require_tpu=True,
     cell, cfg, traffic, bench = common.find_cell(args.workload, root)
     limits = common.load_json(os.path.join(
         root, "bench", "limits", args.workload + ".json"))["limits"]
+    per_layer = common.cell_metrics(bench, cell["name"], "per_layer")
+    # the trace labels the cell's readers declare, checked before JAX
+    # takes a chip
+    declared = {attr: common.declarations(per_layer, attr)
+                for attr in ("SCOPES", "MODULES")}
     import jax
     if require_tpu:
         devs = common.require_chips(jax, cell["chips"])
     else:
         devs = jax.devices()[:cell["chips"]]
     common.use_compile_cache(jax)
-    peaks = (common.peaks(devs[0].device_kind)
-             if require_tpu or args.trace else None)
-    per_layer = common.cell_metrics(bench, cell["name"], "per_layer")
+    peaks = common.peaks(devs[0].device_kind) if require_tpu else None
     from bench import program  # noqa: F401  (puts src/ on the path)
     if traffic["kind"] == "train":
         from bench import train as driver
@@ -66,7 +74,7 @@ def main(argv=None, *, t_start=T_START, require_tpu=True,
     result, checks = driver.run(
         jax, cell, cfg, traffic, limits, seed=args.seed,
         seconds=args.seconds, trace=bool(args.trace), t_start=t_start,
-        devs=devs, peaks=peaks, per_layer=per_layer)
+        devs=devs, peaks=peaks, per_layer=per_layer, declared=declared)
     common.emit(result, checks)
     return result
 

@@ -27,9 +27,6 @@ import numpy as np
 from bench import common, gen, program
 from bench import reference as ref
 
-MODULES = {"prefill": ["jit_prefill"], "decode": ["jit_step_fn"]}
-
-
 class Server:
     """The engine, its weights and the cell's requests for one seed."""
 
@@ -255,7 +252,7 @@ AT_LEAST = ("checked_tokens",)
 
 
 def run(jax, cell, cfg, traffic, limits, *, seed, seconds, trace, t_start,
-        devs, peaks, per_layer):
+        devs, peaks, per_layer, declared):
     sv = Server(jax, cfg, traffic, seed, seconds)
     sv.warm()
     watch = common.WindowWatch(jax, freeze=True)
@@ -306,7 +303,7 @@ def run(jax, cell, cfg, traffic, limits, *, seed, seconds, trace, t_start,
         return result, table
     from bench import trace as tr_mod
     ex = tr_mod.extract(common.newest_trace(prof), {})
-    red = tr_mod.reduce(ex, {}, MODULES)
+    red = tr_mod.reduce(ex, {}, declared["MODULES"])
     busy = [d["busy_ns"] for d in red["devices"].values()]
     result["device"]["busy_s"] = float(np.mean(busy)) / 1e9
     result["device"]["window_s"] = red["window_ns"] / 1e9

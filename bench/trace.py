@@ -145,9 +145,9 @@ def reduce(ex, scopes, modules=None, top=10):
     of ``scopes`` ({label: [substring, ...]}), ns of the ops inside the
     runs of each module of ``modules`` ({label: [module name, ...]}),
     collective ns and the part
-    of it not overlapped by other ops. Plus the window, the busiest
-    device ops and the longest idle gaps with the host span they fall
-    in."""
+    of it not overlapped by other ops. Plus the window, the labels it was
+    reduced by, the busiest device ops and the longest idle gaps with the
+    host span they fall in."""
     w0, w1 = window(ex)
     per_dev = {}
     op_time = {}
@@ -188,7 +188,8 @@ def reduce(ex, scopes, modules=None, top=10):
     for dur, a, b, dev in all_gaps[:top]:
         idle.append([_host_label(host, a, b), dur / 1e9])
     ops_top = sorted(op_time.items(), key=lambda kv: -kv[1])[:top]
-    return {"window_ns": w1 - w0, "devices": per_dev,
+    return {"window_ns": w1 - w0, "scopes": dict(scopes),
+            "modules": dict(modules or {}), "devices": per_dev,
             "device_ops": [[n, t / 1e9] for n, t in ops_top],
             "idle_gaps": idle}
 

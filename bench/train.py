@@ -14,7 +14,10 @@ device from the seed, builds the state, and drives the first
 ``check_calls`` segments through the window's own call: they compile it,
 and they are what the reference follows. The window then runs whole
 segments back to back until ``--seconds`` have passed, the jobs of the
-schedule cycling so that every window holds global rounds.
+schedule cycling so that every window holds global rounds. A traced run
+hands its readers, besides the trace's reduction, the segment's own
+metrics of every call of the window (``ctx["program"]``), fetched once
+the window has closed.
 """
 from __future__ import annotations
 
@@ -371,10 +374,21 @@ def setup_and_check_calls(jax, cfg, traffic, seed, loss_wrap=None,
     return tr, prog
 
 
+def program_counters(mets):
+    """The segment's metrics of every call, fetched to the host once and
+    concatenated per key (the per-round arrays of successive calls, end
+    to end)."""
+    import jax
+    return jax.tree.map(
+        lambda *xs: np.concatenate([np.atleast_1d(x) for x in xs]),
+        *jax.device_get(mets))
+
+
 def run(jax, cell, cfg, traffic, limits, *, seed, seconds, trace, t_start,
-        devs, peaks, per_layer):
+        devs, peaks, per_layer, declared):
     tr, prog = setup_and_check_calls(jax, cfg, traffic, seed,
                                      chips=cell["chips"])
+    common.print_memory_peaks(devs, "after the check calls")
     mesh = tr.mesh
     inputs = (tr.k_w, tr.pool[:traffic["check_calls"]],
               tr.Ws_host[:traffic["check_calls"]])
@@ -390,13 +404,15 @@ def run(jax, cell, cfg, traffic, limits, *, seed, seconds, trace, t_start,
     span = jax.profiler.TraceAnnotation("bench.window")
     span.__enter__()
     watch.__enter__()
-    losses = []
+    losses, calls = [], []
     t0 = time.perf_counter()
     n = 0
     pending = None
     while True:
         with jax.profiler.TraceAnnotation("bench.dispatch"):
             mets = tr.call()
+        if trace:
+            calls.append(mets)
         n += 1
         if pending is not None:
             with jax.profiler.TraceAnnotation("bench.wait"):
@@ -413,10 +429,13 @@ def run(jax, cell, cfg, traffic, limits, *, seed, seconds, trace, t_start,
     print(watch.line(), flush=True)
     if trace:
         jax.profiler.stop_trace()
+    common.print_memory_peaks(devs, "after the window")
     window = t1 - t0
     tokens = n * tr.tokens_per_call
     rounds, local_steps = n * tr.S, n * tr.S * tr.H
     device = common.device_info(devs)
+    counters = program_counters(calls) if trace else None
+    del calls
     tr.free()
     del tr
     gc.collect()
@@ -436,22 +455,16 @@ def run(jax, cell, cfg, traffic, limits, *, seed, seconds, trace, t_start,
             "setup_s": {"value": setup_s, "unit": "s"}}
         return result, table
     ex = tr_mod.extract(common.newest_trace(prof), names)
-    red = tr_mod.reduce(ex, SCOPES)
+    red = tr_mod.reduce(ex, declared["SCOPES"])
     busy = [d["busy_ns"] for d in red["devices"].values()]
     result["device"]["busy_s"] = float(np.mean(busy)) / 1e9
     result["device"]["window_s"] = red["window_ns"] / 1e9
     result["breakdown"] = {"device_ops": red["device_ops"],
                            "idle_gaps": red["idle_gaps"]}
     ctx = {"cfg": cfg, "traffic": traffic, "peaks": peaks,
-           "chips": len(devs), "reduced": red,
+           "chips": len(devs), "reduced": red, "program": counters,
            "counts": {"tokens": tokens, "calls": n, "rounds": rounds,
                       "local_steps": local_steps}}
     result["metrics"] = common.read_metrics(per_layer, ctx)
     common.clear_dir(prof)
     return result, table
-
-
-SCOPES = {"local_grad": ["dsgd.local_grad"],
-          "fwd_bwd": ["dsgd.fwd_bwd"],
-          "local_update": ["dsgd.local_update"],
-          "mix": ["panel.", "merge.panel"]}

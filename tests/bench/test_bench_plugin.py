@@ -1,9 +1,11 @@
 """A second model family enters the benchmark through new files and
 entries alone, as a change that adds a model would bring it: in a copy
 of the checkout, a family module, its configuration files, limits, a
-small traffic mix and two cells are added (``data/plugin/``), and a
-training cell and a serving cell of that family run on the CPU with
-``correct`` true. No file of the harness that was there changes."""
+small traffic mix, two cells and a per-layer reader are added
+(``data/plugin/``), and a training cell and a serving cell of that family
+run on the CPU with ``correct`` true; traced, the training cell's new
+reader reads the scope label it declares and a counter of the program.
+No file of the harness that was there changes."""
 import hashlib
 import json
 import os
@@ -31,7 +33,7 @@ def _hashes(top):
     return out
 
 
-def _run(root, cell):
+def _run(root, cell, trace=0):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("PYTHONPATH", None)
     p = subprocess.run(
@@ -39,7 +41,7 @@ def _run(root, cell):
          "import sys; sys.path.insert(0, '.'); from bench import run; "
          "run.main(sys.argv[1:], require_tpu=False)",
          "--workload", cell, "--seed", str(2 ** 33 + 17), "--seconds", "2",
-         "--trace", "0"],
+         "--trace", str(trace)],
         cwd=root, env=env, capture_output=True, text=True, timeout=600)
     assert p.returncode == 0, p.stderr[-4000:]
     return json.loads(p.stdout.strip().splitlines()[-1])
@@ -73,6 +75,8 @@ def plugged(tmp_path_factory):
         json.dump(bench, f)
     results = {w["name"]: _run(root, w["name"])
                for w in entries["workloads"]}
+    results["tinyllama.allreduce", "traced"] = _run(
+        root, "tinyllama.allreduce", trace=1)
     return root, results, before, _hashes(os.path.join(root, "bench"))
 
 
@@ -88,13 +92,26 @@ def test_serving_cell_of_a_second_family_is_correct(plugged):
     assert res["checks"]["checked_tokens"]["value"] >= 10
 
 
+def test_reader_of_a_second_family_reads_its_scope_and_a_counter(plugged):
+    """The reader came as a new file: the trace was reduced by the label
+    it declares, and it read the segment's per-round gradient norms of
+    every call of the window from ``ctx["program"]``."""
+    res = plugged[1]["tinyllama.allreduce", "traced"]
+    assert res["correct"] is True
+    assert set(res["metrics"]) == {"train.plugin_rounds"}
+    assert res["metrics"]["train.plugin_rounds"] == {
+        "value": 8.0 * res["attempted"], "unit": "rounds"}
+
+
 def test_no_harness_file_changed(plugged):
     _, _, before, after = plugged
     assert {k: after[k] for k in before} == before
     added = sorted(set(after) - set(before))
     assert added and all(
-        k.startswith(("models/", "configs/", "limits/", "traffic/"))
+        k.startswith(("models/", "configs/", "limits/", "traffic/",
+                      "metrics/"))
         for k in added), added
+    assert "metrics/train.plugin_rounds.py" in added
 
 
 def test_the_second_family_has_its_own_tree():
