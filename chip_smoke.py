@@ -15,7 +15,9 @@ server, run on a TPU through the entry points a user calls.
 (c) Serve: ``repro.launch.serve`` restores the merged model and serves a
     few requests through 4 slots; each request is compared with a lone
     ``generate`` of it at temperature 0 (tokens; where a token differs,
-    the engine's pick must be a near-tie of the lone run's logits).
+    the engine's pick must be a near-tie of the lone run's logits). On a
+    TPU the engine serves its bfloat16 operand copy of the dense weights
+    (``operand_bytes``), ``generate`` the float32 params.
 (d) ``--chips 4``: phase (a) with the agent rows laid over the host's
     chips (``--mesh host``), against the same seed replicated on one chip:
     per-round losses, the merged model and its eval within ``SHARD_TOL``.
@@ -246,7 +248,8 @@ def run_serve(jax, merged_path):
     dt = time.perf_counter() - t0
     log("c.serve", seconds=f"{dt:.1f}", serve_seconds=f"{t_serve:.1f}",
         requests=len(res["requests"]), bit_exact=exact,
-        near_ties=near_ties, logit_gaps=gaps, peak_bytes=peak_bytes(jax))
+        near_ties=near_ties, logit_gaps=gaps,
+        operand_bytes=res["operand_bytes"], peak_bytes=peak_bytes(jax))
 
 
 def run_sharded(jax):

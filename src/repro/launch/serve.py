@@ -167,7 +167,8 @@ def main(argv=None):
     if args.events:
         print(f"events: {args.events}")
     return {"outputs": out, "requests": reqs, "model": model,
-            "params": params, "max_len": max_len}
+            "params": params, "max_len": max_len,
+            "operand_bytes": snap["operand_bytes"]}
 
 
 if __name__ == "__main__":

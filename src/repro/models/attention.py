@@ -14,7 +14,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import AttentionConfig, LayerSpec, ModelConfig
-from repro.models.layers import apply_mrope, apply_norm, apply_rope, dense_init
+from repro.models.layers import (apply_mrope, apply_norm, apply_rope, dense,
+                                 dense_init)
 from repro.models.sharding import constrain, constrain_pick
 from repro.models.sharding import logical as L
 
@@ -148,9 +149,9 @@ def gqa_forward(params, x, *, cfg: ModelConfig, lspec: LayerSpec,
     a = cfg.attn
     B, S, _ = x.shape
     _hpick = [(-2, "model"), (-1, "model")]
-    q = (x @ params["wq"]).reshape(B, S, a.num_heads, a.head_dim)
-    k = (x @ params["wk"]).reshape(B, S, a.num_kv_heads, a.head_dim)
-    v = (x @ params["wv"]).reshape(B, S, a.num_kv_heads, a.head_dim)
+    q = dense(x, params["wq"]).reshape(B, S, a.num_heads, a.head_dim)
+    k = dense(x, params["wk"]).reshape(B, S, a.num_kv_heads, a.head_dim)
+    v = dense(x, params["wv"]).reshape(B, S, a.num_kv_heads, a.head_dim)
     q = constrain_pick(q, [(-4, "fsdp")], _hpick)
     k = constrain_pick(k, [(-4, "fsdp")], _hpick)
     v = constrain_pick(v, [(-4, "fsdp")], _hpick)
@@ -189,7 +190,7 @@ def gqa_forward(params, x, *, cfg: ModelConfig, lspec: LayerSpec,
             new_cache = _prefill_cache(cfg, lspec, k, v, positions, B, S,
                                        cache_max_len or S)
 
-    y = y.reshape(B, S, a.q_dim) @ params["wo"]
+    y = dense(y.reshape(B, S, a.q_dim), params["wo"])
     return y, new_cache
 
 
@@ -370,8 +371,10 @@ def cross_kv(params, enc_out, *, cfg: ModelConfig):
     max_len-sized buffer) keeps its padding masked out."""
     a = cfg.attn
     B, Se, _ = enc_out.shape
-    k = (enc_out @ params["wk"]).reshape(B, Se, a.num_kv_heads, a.head_dim)
-    v = (enc_out @ params["wv"]).reshape(B, Se, a.num_kv_heads, a.head_dim)
+    k = dense(enc_out, params["wk"]).reshape(B, Se, a.num_kv_heads,
+                                             a.head_dim)
+    v = dense(enc_out, params["wv"]).reshape(B, Se, a.num_kv_heads,
+                                             a.head_dim)
     pos = jnp.broadcast_to(jnp.arange(Se, dtype=jnp.int32), (B, Se))
     return {"k": k, "v": v, "pos": pos}
 
@@ -381,7 +384,7 @@ def cross_forward(params, x, kv, *, cfg: ModelConfig):
     a = cfg.attn
     B, S, _ = x.shape
     Se = kv["k"].shape[1]
-    q = (x @ params["wq"]).reshape(B, S, a.num_heads, a.head_dim)
+    q = dense(x, params["wq"]).reshape(B, S, a.num_heads, a.head_dim)
     if "pos" in kv:  # mask padded encoder slots (pos == -1)
         bias = jnp.where(kv["pos"][:, None, :] >= 0, 0.0, NEG_INF
                          ).astype(jnp.float32)
@@ -389,4 +392,4 @@ def cross_forward(params, x, kv, *, cfg: ModelConfig):
     else:
         bias = jnp.zeros((B, S, Se), jnp.float32)
     y = _sdpa(q, kv["k"], kv["v"], bias, 1.0 / np.sqrt(a.head_dim))
-    return y.reshape(B, S, a.q_dim) @ params["wo"]
+    return dense(y.reshape(B, S, a.q_dim), params["wo"])

@@ -112,6 +112,23 @@ def apply_mrope(x, positions3, sections, theta=1000000.0):
 
 
 # ---------------------------------------------------------------------------
+# dense matmuls
+# ---------------------------------------------------------------------------
+
+
+def dense(x, w):
+    """``x @ w`` for a weight consumed only as a dense matmul's right
+    operand. A float32 activation against a bfloat16 weight (the serving
+    engine's operand copy, ``Model.operand_params``) multiplies bfloat16
+    operands with float32 accumulation and returns float32: on a TPU at
+    the default precision the product of two float32 operands, made
+    without rounding the weight again. Any other pair is ``x @ w``."""
+    if x.dtype == jnp.float32 and w.dtype == jnp.bfloat16:
+        return jnp.matmul(x.astype(w.dtype), w, preferred_element_type=x.dtype)
+    return x @ w
+
+
+# ---------------------------------------------------------------------------
 # MLPs
 # ---------------------------------------------------------------------------
 
@@ -133,13 +150,13 @@ def spec_mlp(gated=True):
 
 
 def apply_mlp(params, x, act_fn, gated=True):
-    h = x @ params["w_in"]
+    h = dense(x, params["w_in"])
     h = constrain(h, ("fsdp", None, "model"))
     if gated:
-        h = act_fn(x @ params["w_gate"]) * h
+        h = act_fn(dense(x, params["w_gate"])) * h
     else:
         h = act_fn(h)
-    return h @ params["w_out"]
+    return dense(h, params["w_out"])
 
 
 # ---------------------------------------------------------------------------

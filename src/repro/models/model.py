@@ -40,6 +40,7 @@ class Model:
     init_cache: Callable  # (B, seq_len, dtype) -> caches
     cache_spec: Callable  # () -> logical spec tree
     input_specs: Callable  # (shape, agents) -> dict of ShapeDtypeStructs
+    operand_params: Callable  # (params, dtype) -> params, matmul operands cast
 
 
 def build_model(cfg: ModelConfig) -> Model:
@@ -204,6 +205,26 @@ def build_model(cfg: ModelConfig) -> Model:
         logits = (h @ head_w(params)).astype(jnp.float32)[:, 0]
         return logits, new_caches
 
+    def operand_params(params, dtype):
+        """``params`` with each leaf consumed only as the right operand of a
+        dense matmul (:func:`layers.dense`) cast to ``dtype``: the
+        projections of standard and cross attention and the dense MLP
+        matrices of the decoder and encoder stacks. The tied embedding
+        (also the token lookup), norms, an untied head, MLA, recurrent
+        cells and expert layers stay as they are; the tree's structure and
+        shapes do not change."""
+        def cast(w):
+            return w.astype(dtype)
+
+        out = dict(params)
+        out["decoder"] = tfm.operand_stack(params["decoder"], cfg, cast)
+        if is_encdec:
+            enc_cfg = cfg.replace(num_layers=cfg.encoder_layers,
+                                  dense_ff_first_k=0)
+            out["encoder"] = tfm.operand_stack(params["encoder"], enc_cfg,
+                                               cast)
+        return out
+
     def init_cache(B, seq_len, dtype=None, enc_len: int = 0):
         dtype = dtype or dt
         return tfm.init_stack_cache(cfg, B, seq_len, cross=is_encdec,
@@ -260,4 +281,4 @@ def build_model(cfg: ModelConfig) -> Model:
     return Model(cfg=cfg, init_params=init_params, param_spec=param_spec,
                  loss_fn=loss_fn, prefill=prefill, decode_step=decode_step,
                  init_cache=init_cache, cache_spec=cache_spec,
-                 input_specs=input_specs)
+                 input_specs=input_specs, operand_params=operand_params)

@@ -233,6 +233,31 @@ def spec_stack(cfg: ModelConfig, cross: bool = False):
     return out
 
 
+def operand_block(params, lspec: LayerSpec, cast):
+    """A block's params with ``cast`` applied to each leaf the block
+    consumes only as the right operand of :func:`layers.dense`: the
+    projections of standard and cross attention and a dense MLP's
+    matrices. Norms, MLA, recurrent cells and expert layers are kept."""
+    parts = ["cross"]
+    if lspec.mixer == "gqa":
+        parts.append("mixer")
+    if lspec.ffn not in ("none", "moe"):
+        parts.append("ffn")
+    p = dict(params)
+    for part in parts:
+        if part in p:
+            p[part] = jax.tree.map(cast, p[part])
+    return p
+
+
+def operand_stack(params, cfg: ModelConfig, cast):
+    """:func:`operand_block` over every block of a stack's params."""
+    return {seg.name: {f"p{i}": operand_block(params[seg.name][f"p{i}"], ls,
+                                              cast)
+                       for i, ls in enumerate(seg.specs)}
+            for seg in build_segments(cfg)}
+
+
 def init_stack_cache(cfg: ModelConfig, B: int, seq_len: int,
                      cross: bool = False, enc_len: int = 0,
                      dtype=jnp.float32):

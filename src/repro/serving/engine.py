@@ -29,6 +29,23 @@ Sampling masks logits columns >= ``cfg.vocab_size`` to -inf first: the LM
 head projects to ``cfg.padded_vocab`` (models/model.py) and the padding
 columns carry random-init weights, so unmasked greedy/temperature sampling
 can emit out-of-vocab ids.
+
+**Matmul operands.** Where a float32 matmul at the precision in force is
+one pass over bfloat16 operands (a TPU at the default precision,
+:func:`f32_matmul_is_bf16_pass`), the engine holds the dense weight
+matrices as those operands: when it takes the params it casts each leaf
+``Model.operand_params`` names (the attention projections and dense MLP
+matrices) to bfloat16 once, in one jitted program, and keeps every other
+leaf as the caller's. ``models.layers.dense`` multiplies a float32
+activation by such a weight with float32 accumulation, so no prefill or
+decode step rounds the weights again; the products are the ones the
+float32 weights give on the MXU. (A one-row matmul, the decode step of a
+one-slot engine, the TPU compiler lowers to a float32 multiply-reduce:
+there the float32 weights gave a finer product than the bf16 operands.)
+Elsewhere (CPU, or ``highest`` precision) the engine serves the params
+as given. ``stats`` (and :meth:`snapshot`)
+report the cast leaves and their bytes, ``operand_leaves`` and
+``operand_bytes``, both 0 where nothing was cast.
 """
 from __future__ import annotations
 
@@ -71,6 +88,40 @@ def sample_token(logits, rng, temperature: float = 0.0,
         return jnp.argmax(logits, axis=-1).astype(jnp.int32)
     return jax.random.categorical(rng, logits / temperature, axis=-1).astype(
         jnp.int32)
+
+
+# ---------------------------------------------------------------------------
+# matmul operands
+# ---------------------------------------------------------------------------
+
+# values of jax_default_matmul_precision at which a TPU multiplies float32
+# operands in one bfloat16 pass (None: not set)
+_ONE_PASS_PRECISIONS = (None, "default", "bfloat16")
+
+
+def f32_matmul_is_bf16_pass() -> bool:
+    """Whether a float32 matmul here rounds both operands to bfloat16 and
+    multiplies them in one pass with float32 accumulation: on a TPU, at
+    the default matmul precision."""
+    return (jax.default_backend() == "tpu"
+            and jax.config.jax_default_matmul_precision
+            in _ONE_PASS_PRECISIONS)
+
+
+def operand_copy(model, params):
+    """(params to serve, number of leaves cast, their bytes): the leaves
+    ``model.operand_params`` names cast to bfloat16 in one jitted program
+    where :func:`f32_matmul_is_bf16_pass`, every other leaf the caller's
+    own; else ``params`` as given."""
+    if not f32_matmul_is_bf16_pass():
+        return params, 0, 0
+    cast = jax.jit(model.operand_params, static_argnums=1)(params,
+                                                          jnp.bfloat16)
+    out = jax.tree.map(lambda c, p: c if c.dtype != p.dtype else p,
+                       cast, params)
+    done = [c for c, p in zip(jax.tree.leaves(out), jax.tree.leaves(params))
+            if c is not p]
+    return out, len(done), sum(c.nbytes for c in done)
 
 
 # ---------------------------------------------------------------------------
@@ -247,7 +298,8 @@ class ServingEngine:
                  max_len: int = 128, eos_id: Optional[int] = None,
                  temperature: float = 0.0, rng=None, pad_id: int = 0,
                  events=None):
-        self.model, self.params = model, params
+        self.model = model
+        self.params, n_cast, cast_bytes = operand_copy(model, params)
         self.cfg = model.cfg
         self.C, self.max_len = int(max_concurrency), int(max_len)
         self.eos_id = eos_id
@@ -276,7 +328,8 @@ class ServingEngine:
         self.queue: collections.deque = collections.deque()
         self.results: Dict[Any, np.ndarray] = {}
         self.stats = {"capacity": self.C, "ticks": 0, "live_slot_ticks": 0,
-                      "admitted": 0, "retired": 0, "prefill_tokens": 0}
+                      "admitted": 0, "retired": 0, "prefill_tokens": 0,
+                      "operand_leaves": n_cast, "operand_bytes": cast_bytes}
         self.hists = histogram_set(
             ("ttft_s", "queue_wait_s", "decode_step_s", "per_token_s"))
         self.records: collections.deque = collections.deque(

@@ -17,8 +17,9 @@ from repro.configs import get_config
 from repro.configs.base import LayerSpec
 from repro.models import moe as moe_mod
 from repro.models import recurrent as rec
-from repro.models.layers import (apply_rope, chunked_softmax_xent, init_mlp,
-                                 apply_norm, init_norm)
+from repro.models import build_model
+from repro.models.layers import (apply_rope, chunked_softmax_xent, dense,
+                                 init_mlp, apply_norm, init_norm)
 
 
 # ---------------------------------------------------------------- MoE
@@ -219,3 +220,73 @@ def test_nonparam_ln_has_no_params():
     y = apply_norm(p, x, "nonparam_ln")
     np.testing.assert_allclose(jnp.mean(y, -1), 0.0, atol=1e-5)
     np.testing.assert_allclose(jnp.var(y, -1), 1.0, atol=1e-3)
+
+
+# ---------------------------------------------------------------- dense
+
+
+@pytest.mark.parametrize("xshape", [(5, 16), (2, 3, 16)])
+def test_dense_of_f32_operands_is_plain_matmul(xshape):
+    """Float32 weights (every training path) keep ``x @ w``'s jaxpr."""
+    x = jnp.ones(xshape, jnp.float32)
+    w = jnp.ones((16, 8), jnp.float32)
+    assert (str(jax.make_jaxpr(dense)(x, w))
+            == str(jax.make_jaxpr(lambda x, w: x @ w)(x, w)))
+
+
+def test_dense_of_bf16_weight_accumulates_in_f32():
+    """A float32 activation against a bfloat16 weight: bfloat16 operands,
+    float32 accumulation and result, the weight never promoted back."""
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 3, 16), jnp.float32)
+    w = jax.random.normal(jax.random.PRNGKey(1), (16, 8)).astype(jnp.bfloat16)
+    y = dense(x, w)
+    assert y.dtype == jnp.float32
+    np.testing.assert_array_equal(
+        y, jnp.matmul(x.astype(jnp.bfloat16), w,
+                      preferred_element_type=jnp.float32))
+    jaxpr = jax.make_jaxpr(dense)(x, w).jaxpr
+    casts = [e for e in jaxpr.eqns if e.primitive.name == "convert_element_type"]
+    assert [c.invars[0].aval.shape for c in casts] == [x.shape]
+    xb = x.astype(jnp.bfloat16)  # bf16 activations: unchanged, bf16 out
+    assert dense(xb, w).dtype == jnp.bfloat16
+    assert (str(jax.make_jaxpr(dense)(xb, w))
+            == str(jax.make_jaxpr(lambda x, w: x @ w)(xb, w)))
+
+
+# leaf names each family's operand_params casts; every other leaf is kept
+_OPERANDS = {"wq", "wk", "wv", "wo", "w_in", "w_gate", "w_out"}
+
+
+@pytest.mark.parametrize("arch,kw,cast_parts", [
+    ("olmo-1b", {}, {"decoder/main/p0/mixer", "decoder/main/p0/ffn"}),
+    # (rglru, rglru, local attention): the recurrent mixers are kept
+    ("recurrentgemma-2b", {"layers": 3},
+     {"decoder/main/p0/ffn", "decoder/main/p1/ffn", "decoder/main/p2/ffn",
+      "decoder/main/p2/mixer"}),
+    ("seamless-m4t-medium", {},
+     {"decoder/main/p0/mixer", "decoder/main/p0/cross",
+      "decoder/main/p0/ffn", "encoder/main/p0/mixer",
+      "encoder/main/p0/ffn"}),
+    # MLA everywhere, experts after the dense front layer: only its MLP
+    ("deepseek-v3-671b", {"layers": 2}, {"decoder/front/p0/ffn"}),
+])
+def test_operand_params_casts_only_dense_matmul_operands(arch, kw,
+                                                         cast_parts):
+    """Attention projections (standard and cross) and dense MLP matrices
+    are cast; the embedding, norms, MLA, recurrent cells and expert layers
+    keep their dtype; structure and shapes do not change."""
+    cfg = get_config(arch).reduced(d_model=64, vocab=64, **kw)
+    model = build_model(cfg)
+    params = model.init_params(jax.random.PRNGKey(0))
+    out = model.operand_params(params, jnp.bfloat16)
+    assert jax.tree.structure(out) == jax.tree.structure(params)
+    got = dict(jax.tree_util.tree_flatten_with_path(out)[0])
+    cast = set()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
+        new = got[path]
+        assert new.shape == leaf.shape
+        if new.dtype == leaf.dtype:
+            continue
+        assert new.dtype == jnp.bfloat16 and path[-1].key in _OPERANDS
+        cast.add("/".join(k.key for k in path[:-1]))
+    assert cast == cast_parts

@@ -15,6 +15,7 @@ from repro.core import dsgd
 from repro.core.gossip import merged_model
 from repro.models import build_model
 from repro.optim import make_optimizer
+from repro.serving import engine as engine_mod
 from repro.serving import (Request, ServingEngine, generate, make_decode_fn,
                            make_prefill_fn, mask_oov, sample_token)
 
@@ -171,17 +172,37 @@ def test_engine_cache_buffer_persists_across_ticks():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("arch,kw", [
+_PARITY_ARCHS = [
     ("olmo-1b", {}),                      # GQA, tied embeddings
     ("recurrentgemma-2b", {"layers": 3}),  # RG-LRU + local sliding window
     ("seamless-m4t-medium", {}),          # enc-dec: padded cross-KV rows
-])
-def test_continuous_batching_bit_identical_to_sequential(arch, kw):
+]
+
+
+def _force_operands(monkeypatch):
+    """Make the engine take its bfloat16 operand copy, as on a TPU at the
+    default precision."""
+    monkeypatch.setattr(engine_mod, "f32_matmul_is_bf16_pass", lambda: True)
+
+
+@pytest.mark.parametrize("arch,kw,operands", [
+    pytest.param(arch, kw, operands,
+                 id=f"{arch}-kw{i}" + ("-operands" if operands else ""))
+    for operands in (False, True)
+    for i, (arch, kw) in enumerate(_PARITY_ARCHS)])
+def test_continuous_batching_bit_identical_to_sequential(arch, kw, operands,
+                                                         monkeypatch):
     """N heterogeneous requests through the slotted engine produce
-    bit-identical tokens to N single-request generate calls (temp 0)."""
+    bit-identical tokens to N single-request generate calls (temp 0) given
+    the params the engine serves: the caller's, or its bfloat16 operand
+    copy where that is forced on."""
     cfg, model, params = _tiny(arch, **kw)
+    if operands:
+        _force_operands(monkeypatch)
     max_len = 48
     eng = ServingEngine(model, params, max_concurrency=3, max_len=max_len)
+    assert (eng.stats["operand_leaves"] > 0) == operands
+    params = eng.params
     reqs = []
     for i in range(5):
         S = [8, 12][i % 2]
@@ -199,6 +220,83 @@ def test_continuous_batching_bit_identical_to_sequential(arch, kw):
         ref = generate(model, params, _batch_of(r), r.max_new,
                        max_len=max_len)[0]
         np.testing.assert_array_equal(out[r.rid], ref)
+
+
+def _eqns(jaxpr):
+    """Every equation of a closed jaxpr, nested ones (jit, scan) included."""
+    for e in jaxpr.eqns:
+        yield e
+        for v in e.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                if hasattr(sub, "jaxpr") and hasattr(sub.jaxpr, "eqns"):
+                    yield from _eqns(sub.jaxpr)
+                elif hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+def _operand_leaves(params):
+    """(path, leaf) of the decoder's dense matmul operands (olmo-like)."""
+    names = {"wq", "wk", "wv", "wo", "w_in", "w_gate", "w_out"}
+    return [(p, x) for p, x in
+            jax.tree_util.tree_flatten_with_path(params["decoder"])[0]
+            if p[-1].key in names]
+
+
+def test_engine_operand_copy_is_never_cast_again(monkeypatch):
+    """With the decision forced on: the decode step converts no weight, the
+    snapshot reports the cast leaves and their bytes, and a decode step's
+    logits agree with the float32-param engine's to bfloat16 rounding."""
+    cfg, model, params = _tiny(d=64, vocab=64)
+    eng32 = ServingEngine(model, params, max_concurrency=3, max_len=40)
+    _force_operands(monkeypatch)
+    eng = ServingEngine(model, params, max_concurrency=3, max_len=40)
+    ops = _operand_leaves(eng.params)
+    assert ops and all(x.dtype == jnp.bfloat16 for _, x in ops)
+    assert eng.params["embed"]["table"] is params["embed"]["table"]
+    snap = eng.snapshot()
+    assert snap["operand_leaves"] == len(ops) == 7
+    assert snap["operand_bytes"] == sum(2 * x.size for _, x in ops)
+    # no weight (a whole stack or one layer's slice) is converted in the step
+    shapes = {x.shape for _, x in ops} | {x.shape[1:] for _, x in ops}
+    C = eng.C
+    jaxpr = jax.make_jaxpr(eng._step_fn)(
+        eng.params, eng.caches, jnp.zeros((C,), jnp.int32),
+        jnp.zeros((C,), jnp.int32), jax.random.PRNGKey(0))
+    eqns = list(_eqns(jaxpr.jaxpr))
+    casts = [e for e in eqns if e.primitive.name == "convert_element_type"]
+    assert any(e.params["new_dtype"] == jnp.bfloat16 for e in casts)
+    assert not [e for e in casts if e.invars[0].aval.shape in shapes]
+    # each weight meets a bfloat16 activation: a dot of mixed dtypes would
+    # have the compiler round the weight up to float32 in every step
+    dots = [[v.aval.dtype for v in e.invars] for e in eqns
+            if e.primitive.name == "dot_general"]
+    assert dots.count([jnp.bfloat16, jnp.bfloat16]) == len(ops)
+    assert all(a == b for a, b in dots)
+    # one decode step after a prefill, against the float32 weights
+    batch = {"tokens": jnp.asarray(_prompt(0, 12, cfg.vocab_size)[None])}
+    out = []
+    for e in (eng32, eng):
+        logits, row = e._prefill(e.params, batch)
+        tok = jnp.argmax(logits[:, :cfg.vocab_size], -1)[:, None]
+        logits, _ = jax.jit(model.decode_step)(e.params, row, tok, 12)
+        out.append(np.asarray(logits, np.float64))
+    ref, got = out
+    # a few bfloat16 roundings (2**-8 relative each) through two layers
+    err = np.linalg.norm(got - ref) / np.linalg.norm(ref)
+    assert 0.0 < err < 2e-2
+
+
+def test_engine_serves_callers_params_on_cpu():
+    """On a CPU the decision is off: the engine's params are the caller's
+    own leaves and nothing is reported cast."""
+    assert jax.default_backend() == "cpu"
+    assert not engine_mod.f32_matmul_is_bf16_pass()
+    _, model, params = _tiny()
+    eng = ServingEngine(model, params, max_concurrency=2, max_len=32)
+    for a, b in zip(jax.tree.leaves(eng.params), jax.tree.leaves(params)):
+        assert a is b
+    snap = eng.snapshot()
+    assert snap["operand_leaves"] == 0 and snap["operand_bytes"] == 0
 
 
 def test_mixed_batch_multimodal_prefix_parity():
